@@ -1286,4 +1286,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime.config import enable_compile_cache
+
+    enable_compile_cache()
     main()

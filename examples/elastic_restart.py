@@ -83,6 +83,7 @@ print("elastic restart OK")
 def main():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"  # 8 virtual host devices, never a chip
     env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent / "src")
     r = subprocess.run([sys.executable, "-c", BODY], env=env)
     raise SystemExit(r.returncode)

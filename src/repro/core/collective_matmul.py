@@ -29,30 +29,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core.lanes import two_lane_ring
 
 
-def _pvary(x: jax.Array, axis_name: str) -> jax.Array:
+def _varying(x: jax.Array, axis_name: str) -> jax.Array:
     """Mark a replicated value as device-varying along ``axis_name``.
 
     shard_map's vma type system requires loop carries that *become* varying
     (our ring buffers do, after the first ppermute) to start varying."""
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, (axis_name,))
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis_name,), to="varying")  # older spelling
-    return x  # pre-vma JAX: no replication types, nothing to declare
-
-
-def _axis_size(axis_name: str) -> int:
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)  # pre-0.5 spelling
-
-
-def _axis_index(axis_name: str) -> jax.Array:
-    return lax.axis_index(axis_name)
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 # --------------------------------------------------------------------------
@@ -90,8 +75,8 @@ def allgather_matmul(
     The ppermute for step ``s+1`` is issued before step ``s``'s matmul —
     transfer lane producing, compute lane consuming.
     """
-    p = _axis_size(axis_name)
-    d = _axis_index(axis_name)
+    p = lax.axis_size(axis_name)
+    d = lax.axis_index(axis_name)
     s_loc, k = x.shape
     n = w.shape[1]
     perm = [(i, (i - 1) % p) for i in range(p)]
@@ -106,7 +91,7 @@ def allgather_matmul(
         acc = lax.dynamic_update_slice(acc, buf @ w, (src * s_loc, jnp.int32(0)))
         return acc
 
-    acc0 = _pvary(
+    acc0 = _varying(
         jnp.zeros((p * s_loc, n), dtype=jnp.promote_types(x.dtype, w.dtype)),
         axis_name,
     )
@@ -128,8 +113,8 @@ def matmul_reducescatter(
     produces chunk partials, transfer lane (ppermute) is the consumer carrying
     the running sum — the same SPSC ring with the roles mirrored.
     """
-    p = _axis_size(axis_name)
-    d = _axis_index(axis_name)
+    p = lax.axis_size(axis_name)
+    d = lax.axis_index(axis_name)
     s = y.shape[0]
     s_loc = s // p
     k = w.shape[1]
@@ -140,7 +125,7 @@ def matmul_reducescatter(
     # must therefore contribute its partial for chunk ``(d - t) % p``. After
     # the add, the buffer permutes one hop toward home. The buffer *is* the
     # SPSC slot; compute lane produces partials, transfer lane consumes them.
-    buf0 = _pvary(jnp.zeros((s_loc, k), dtype=jnp.float32), axis_name)  # f32 ring acc
+    buf0 = _varying(jnp.zeros((s_loc, k), dtype=jnp.float32), axis_name)  # f32 ring acc
 
     def body(step, buf):
         c = (d - step) % p
@@ -165,8 +150,8 @@ def allgather_matmul_gated(
     """Fused two-lane ring: one x-chunk transfer feeds BOTH gate and up
     matmuls (halves ring traffic vs two separate AG-matmuls); elementwise
     act(g)*u happens on the consumer lane. Output: [S, N/p]."""
-    p = _axis_size(axis_name)
-    d = _axis_index(axis_name)
+    p = lax.axis_size(axis_name)
+    d = lax.axis_index(axis_name)
     s_loc, k = x.shape
     n = w_gate.shape[1]
     perm = [(i, (i - 1) % p) for i in range(p)]
@@ -186,7 +171,7 @@ def allgather_matmul_gated(
         h = g * u
         return lax.dynamic_update_slice(acc, h, (src * s_loc, jnp.int32(0)))
 
-    acc0 = _pvary(
+    acc0 = _varying(
         jnp.zeros((p * s_loc, n), dtype=jnp.promote_types(x.dtype, w_gate.dtype)),
         axis_name,
     )
@@ -215,7 +200,7 @@ def mlp_ring(cfg_act: str, x: jax.Array, w_gate, w_up, w_down,
         out = matmul_reducescatter(h, wd, axis_name, unroll=unroll)
         return out.reshape(b, s_loc, wd.shape[1]).astype(xl.dtype)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(None, axis_name, None), P(None, axis_name),
@@ -244,7 +229,7 @@ def tp_allgather_matmul(
     def local(x, w):
         return fn(x, w, axis_name)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis_name, None), P(None, axis_name)),
@@ -267,7 +252,7 @@ def tp_matmul_reducescatter(
     def local(y, w):
         return fn(y, w, axis_name)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(None, axis_name), P(axis_name, None)),

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def pipeline_apply(
     stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -86,12 +84,10 @@ def pipeline_apply(
                 jnp.minimum(write_idx, m - 1), 0)
             return out_buf, outputs
 
-        buf0 = jnp.zeros(mb_shape, x_all.dtype)
-        if hasattr(lax, "pvary"):
-            buf0 = lax.pvary(buf0, (axis_name,))
-        outputs0 = jnp.zeros((m,) + mb_shape, x_all.dtype)
-        if hasattr(lax, "pvary"):
-            outputs0 = lax.pvary(outputs0, (axis_name,))
+        buf0 = lax.pcast(jnp.zeros(mb_shape, x_all.dtype), (axis_name,),
+                         to="varying")
+        outputs0 = lax.pcast(jnp.zeros((m,) + mb_shape, x_all.dtype),
+                             (axis_name,), to="varying")
         _, outputs = lax.fori_loop(0, ticks, tick, (buf0, outputs0))
         # only the last stage holds real outputs; broadcast them to every pod
         # (psum of one-hot contributions — replicated result).
@@ -102,7 +98,7 @@ def pipeline_apply(
     n_leading = {a.shape[0] for a in jax.tree.leaves(stage_params)}
     assert n_leading == {n_stages}, (n_leading, n_stages)
     in_specs = (jax.tree.map(lambda _: P(axis_name), stage_params), P())
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=P(),
         axis_names={axis_name},
     )(stage_params, x_mb)

@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-Dispatch policy: on TPU backends the compiled kernels run natively; on any
-other backend (this CPU container) `interpret=True` executes the kernel body
-in Python for correctness validation. Shapes that don't satisfy a kernel's
+Dispatch policy: on TPU backends the compiled kernels run natively; on the
+CPU backend `interpret=True` executes the kernel body in Python for
+correctness validation; any other backend is an error, not a silent
+interpreter fallback. Shapes that don't satisfy a kernel's
 tiling constraints fall back to the jnp reference (production systems need
 the fallback anyway — e.g. whisper's 1500-frame encoder).
 """
@@ -23,7 +24,12 @@ from repro.kernels.wkv6 import wkv6_bhtk
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise NotImplementedError(
+            f"Pallas kernels run natively on tpu or interpreted on cpu; "
+            f"backend {backend!r} has neither")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))
@@ -77,10 +83,9 @@ def wkv6(r, k, v, logw, u, *, chunk=64):
 def ssd(x, a, b, c, *, chunk=128):
     """x [B,T,H,P]; a [B,T,H]; b/c [B,T,N] in model layout."""
     xt = x.swapaxes(1, 2)
-    at = a.swapaxes(1, 2)
     t = xt.shape[2]
     if t % min(chunk, t):
-        o = ref.ssd_ref(xt, at, b, c)
+        o = ref.ssd_ref(xt, a.swapaxes(1, 2), b, c)
     else:
-        o = ssd_bhtp(xt, at, b, c, chunk=chunk, interpret=_interpret())
+        o = ssd_bhtp(xt, a, b, c, chunk=chunk, interpret=_interpret())
     return o.swapaxes(1, 2)

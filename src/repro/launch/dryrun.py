@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # 512 virtual host devices, never a chip
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell and
 extract the roofline terms from the compiled artifact.
@@ -34,7 +35,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import sharding as shd
 from repro.configs import ARCH_IDS, SHAPES, get_config, shape_applicable
-from repro import compat
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.steps import (
@@ -240,7 +240,7 @@ def _cost_points(cfg: ModelConfig, shape: ShapeConfig, mesh):
 
     def costs(c):
         _, comp, _ = lower_cell(c, shape, mesh)
-        ca = compat.cost_analysis(comp)
+        ca = comp.cost_analysis()
         coll = parse_collectives(comp.as_text())
         return {
             "flops": float(ca.get("flops", 0.0)),

@@ -9,26 +9,20 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
 
-
-def _make(shape, axes):
-    return compat.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """Arbitrary mesh (tests, examples, elastic re-mesh), every axis Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; 2 pods = 512 chips with a leading 'pod' axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests, examples, elastic re-mesh)."""
-    return _make(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever devices exist locally, as a 1D 'data' mesh (examples/CI)."""
-    n = len(jax.devices())
-    return _make((n,), ("data",))
+    return make_mesh((len(jax.devices()),), ("data",))

@@ -1,10 +1,12 @@
-"""Serving driver: batched scan-prefill + greedy decode, served as a
-streaming request through ``repro.serve.ServeScheduler``.
+"""Serving entry point: batched scan-prefill + greedy decode, served as streaming
+requests through ``repro.serve.ServeScheduler``.
 
-The demo form of the serving stack (docs/serving.md): the decode loop is a
-*generator* work function — each generated token is one yielded item, so
-the response's ``first_result_t`` is the time-to-first-token and the
-subsystem's latency accounting applies unchanged to token serving.
+The demo form of the serving stack (docs/serving.md): each request is a
+*generator* work function — each generated token is one yielded
+``(tokens, logits)`` item, so the response's ``first_result_t`` is the
+time-to-first-token and the subsystem's latency accounting applies unchanged
+to token serving. One loaded model answers any number of requests; each
+request allocates its own cache.
 
 Prefill is ``make_prefill_step`` — one jitted ``lax.scan`` dispatch over
 the prompt positions instead of O(prompt_len) ``serve_step`` dispatches
@@ -30,6 +32,60 @@ from repro.models import build_model
 from repro.serve import ServeScheduler
 
 
+def load(cfg):
+    """The model in its serving layout (bf16 params), params from seed 0.
+    Init is jitted so no f32 copy of a whole weight stack is ever live."""
+    model = build_model(cfg.replace(param_dtype="bfloat16"))
+    return model, jax.jit(model.init)(jax.random.PRNGKey(0))
+
+
+def serve(model, params, prompts, *, gen, cache_len, lanes=1, frames=None):
+    """Answer one request per ``[B, P]`` array in ``prompts`` on one loaded
+    model. Returns the finished ``Response`` objects; each one's result is
+    ``gen`` items of ``(tokens [B, 1], logits [B, 1, V])``: the prefill's
+    prediction, then one per decode step. ``frames`` (enc-dec only) holds
+    each request's encoder input."""
+    cfg = model.cfg
+    batch, plen = prompts[0].shape
+    frames = frames or [None] * len(prompts)
+    serve_step = jax.jit(make_serve_step(model), donate_argnums=(1,))
+    prefill = jax.jit(make_prefill_step(model), donate_argnums=(1,))
+
+    def new_cache(req_frames):
+        cache = model.init_cache(batch, cache_len)
+        if cfg.family == "encdec":
+            from repro.models.encdec import encode, prefill_cross_cache
+            cache = prefill_cross_cache(cfg, params, cache,
+                                        encode(cfg, params, req_frames))
+        return cache
+
+    def generate(prompt, req_frames):
+        tok, logits, cache = prefill(params, new_cache(req_frames), prompt)
+        # The prefill prediction is token 0; it is delivered once it exists,
+        # so first_result_t (TTFT) covers the prefill, not its dispatch.
+        yield jax.block_until_ready(tok), logits
+        for t in range(plen, plen + gen - 1):
+            tok, logits, cache = serve_step(params, cache, tok, jnp.int32(t))
+            yield tok, logits
+        jax.block_until_ready(tok)
+
+    # Warm both jits off the served path on one throwaway cache (both donate
+    # their cache argument), so served requests measure steady-state steps,
+    # not compilation.
+    tok, _, cache = prefill(params, new_cache(frames[0]),
+                            jnp.zeros_like(prompts[0]))
+    jax.block_until_ready(serve_step(params, cache, tok, jnp.int32(plen))[0])
+    del cache
+
+    with ServeScheduler(lanes=lanes) as server:
+        client = server.open_client("decode")
+        resps = [client.submit(generate, p, f)
+                 for p, f in zip(prompts, frames)]
+        for resp in resps:
+            assert len(resp.result()) == gen, (len(resp.result()), gen)
+    return resps
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="relic_tiny")
@@ -41,65 +97,22 @@ def main(argv=None):
                     help="RelicPool lanes backing the request server")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch, smoke=args.smoke)
-    cfg = cfg.replace(param_dtype="bfloat16")  # serving layout
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    cache_len = args.prompt_len + args.gen
-    cache = model.init_cache(args.batch, cache_len)
-    serve_step = jax.jit(make_serve_step(model), donate_argnums=(1,))
-    prefill = jax.jit(make_prefill_step(model), donate_argnums=(1,))
-
+    model, params = load(get_config(args.arch, smoke=args.smoke))
+    cfg = model.cfg
     rng = np.random.default_rng(0)
     prompts = jnp.asarray(
         rng.integers(0, cfg.vocab_size, (args.batch, args.prompt_len)),
         jnp.int32)
-
+    frames = None
     if cfg.family == "encdec":
-        from repro.models.encdec import encode, prefill_cross_cache
-        frames = jnp.asarray(
+        frames = [jnp.asarray(
             rng.normal(size=(args.batch, cfg.frontend.n_tokens, cfg.d_model)),
-            jnp.bfloat16)
-        cache = prefill_cross_cache(cfg, params, cache,
-                                    encode(cfg, params, frames))
+            jnp.bfloat16)]
 
-    # Warm the decode jit off the served path (its own throwaway cache —
-    # serve_step donates its cache argument), so the served request
-    # measures steady-state steps, not compilation.
-    warm_cache = model.init_cache(args.batch, cache_len)
-    warm_tok = jnp.zeros((args.batch, 1), jnp.int32)
-    jax.block_until_ready(
-        serve_step(params, warm_cache, warm_tok, jnp.int32(0))[0])
-
-    tok, cache = prefill(params, cache, prompts)
-    jax.block_until_ready(tok)
-
-    steps_timed = [0]  # decode-loop accounting, asserted against gen below
-
-    def decode_stream(first_tok, dcache):
-        def gen():
-            t_tok = first_tok
-            t_cache = dcache
-            yield first_tok  # the prefill prediction is token 0
-            for t in range(args.prompt_len, args.prompt_len + args.gen - 1):
-                t_tok, _, t_cache = serve_step(
-                    params, t_cache, t_tok, jnp.int32(t))
-                steps_timed[0] += 1
-                yield t_tok
-            jax.block_until_ready(t_tok)
-        return gen()
-
-    with ServeScheduler(lanes=args.lanes) as server:
-        client = server.open_client("decode")
-        resp = client.submit(decode_stream, tok, cache)
-        out = resp.result()
-
-    # Token accounting must match the timed step count: one prefill
-    # prediction + one token per timed decode step.
-    assert steps_timed[0] == args.gen - 1, (steps_timed[0], args.gen)
-    assert len(out) == 1 + steps_timed[0], (len(out), steps_timed[0])
-
-    gen_toks = jnp.concatenate(out, axis=1)
+    (resp,) = serve(model, params, [prompts], gen=args.gen,
+                    cache_len=args.prompt_len + args.gen, lanes=args.lanes,
+                    frames=frames)
+    gen_toks = jnp.concatenate([tok for tok, _ in resp.result()], axis=1)
     assert resp.first_result_t is not None and resp.complete_t is not None
     ttft = resp.first_result_t - resp.request.arrival_t
     dt = max(resp.complete_t - resp.first_result_t, 1e-9)
@@ -112,4 +125,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.runtime.config import enable_compile_cache
+
+    enable_compile_cache()
     main()

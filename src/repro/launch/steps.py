@@ -114,22 +114,28 @@ def make_prefill_step(model: Model):
     one-at-a-time loop by ``tests/test_serve.py``.
 
     Returns ``prefill(params, cache, prompts[B, P]) -> (next_tokens[B, 1],
-    cache)`` where ``next_tokens`` is the greedy prediction after the full
-    prompt — exactly what the first decode step consumes.
+    logits[B, 1, V], cache)`` — ``serve_step``'s triple, where
+    ``next_tokens`` is the greedy prediction after the full prompt (exactly
+    what the first decode step consumes) and ``logits`` are the last prompt
+    position's.
     """
 
     def prefill(params, cache, prompts):
         toks = jnp.swapaxes(prompts, 0, 1)[:, :, None]        # [P, B, 1]
         positions = jnp.arange(prompts.shape[1], dtype=jnp.int32)
 
-        def body(cache, xs):
+        def body(carry, xs):
+            cache, _ = carry
             tok, pos = xs
             logits, cache = model.decode_step(params, cache, tok, pos)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return cache, nxt
+            return (cache, logits), None
 
-        cache, nxts = jax.lax.scan(body, cache, (toks, positions))
-        return nxts[-1], cache
+        logits0 = jnp.zeros((prompts.shape[0], 1, model.cfg.vocab_size),
+                            jnp.float32)
+        (cache, logits), _ = jax.lax.scan(body, (cache, logits0),
+                                          (toks, positions))
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return nxt, logits, cache
 
     return prefill
 

@@ -87,6 +87,7 @@ def main(argv=None):
 
         mon = StragglerMonitor(n_hosts=1)
         t_last = time.time()
+        metrics = {"loss": float("nan")}  # resumed at --steps: no step left
         try:
             for i in range(start, args.steps):
                 batch = {k: jnp.asarray(v)
@@ -115,4 +116,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.runtime.config import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -47,6 +47,12 @@ Two families:
     mismatch); ``0`` skips both (the pre-PR-10 format, still restorable —
     entries without a checksum are simply not verified).
 
+``JAX_COMPILATION_CACHE_DIR``
+    Where JAX keeps its persistent compilation cache. JAX reads the
+    variable itself; ``enable_compile_cache()``, called from each entry
+    point's ``__main__``, points the cache at ``<repo>/.jax_cache`` only
+    when it is unset.
+
 ``resolve_serve_config()`` / ``resolve_supervise_config()`` /
 ``resolve_checkpoint_config()`` return frozen snapshots recorded in BENCH
 meta alongside the spin cadence, so a recorded run's knob state is
@@ -57,7 +63,27 @@ from __future__ import annotations
 
 import os
 from dataclasses import asdict, dataclass
+from pathlib import Path
 from typing import Optional
+
+# The fixed in-checkout compile cache (git-ignored): a fixed path, because
+# the path is part of the cache key and a moving directory never hits.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory: ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads
+    it itself, so no directory is set in code), else ``COMPILE_CACHE_DIR``.
+    Called from ``__main__`` blocks only, never at import or from a
+    ``main()`` that tests run in-process."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def _default_spin_yield() -> int:

@@ -22,6 +22,10 @@ import jax.numpy as jnp
 import numpy as np
 
 INF = jnp.float32(1e9)
+# Matvecs over non-0/1 values (rank, path counts, dependencies) run at f32
+# precision: the TPU's default rounds f32 matmul operands to bf16, beyond
+# what the oracles allow. On the CPU it changes nothing.
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def kronecker_graph(scale: int = 5, edge_factor: int = 16, seed: int = 10):
@@ -110,7 +114,7 @@ def pagerank(adj: jax.Array, iters: int = 20, d: float = 0.85) -> jax.Array:
     p = jnp.full((n,), 1.0 / n, jnp.float32)
 
     def body(_, p):
-        spread = adj.T @ (p / deg)
+        spread = jnp.matmul(adj.T, p / deg, precision=_F32)
         return (1 - d) / n + d * spread
 
     return jax.lax.fori_loop(0, iters, body, p)
@@ -150,7 +154,7 @@ def betweenness_centrality(adj: jax.Array, source: int = 0,
 
     def fwd(carry):
         dist, sigma, frontier, level = carry
-        contrib = adj.T @ (sigma * frontier)
+        contrib = jnp.matmul(adj.T, sigma * frontier, precision=_F32)
         nxt = (adj.T @ frontier.astype(jnp.float32) > 0) & (dist < 0)
         sigma = jnp.where(nxt, contrib, sigma)
         dist = jnp.where(nxt, level + 1, dist)
@@ -171,7 +175,7 @@ def betweenness_centrality(adj: jax.Array, source: int = 0,
         on_next = (dist == level).astype(jnp.float32)
         coeff = jnp.where(sigma > 0, (1.0 + delta) / jnp.maximum(sigma, 1e-9),
                           0.0) * on_next
-        contrib = (adj @ coeff) * sigma
+        contrib = jnp.matmul(adj, coeff, precision=_F32) * sigma
         on_this = (dist == level - 1).astype(jnp.float32)
         return delta + contrib * on_this
 

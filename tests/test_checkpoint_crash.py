@@ -305,3 +305,16 @@ def test_train_resume_after_mid_save_kill(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "resumed from step 5" in out
     assert np.isfinite(loss)
+
+
+def test_train_resume_with_no_steps_left(tmp_path, capsys):
+    """--resume when the last save is already at --steps takes no step and
+    reports no loss (NaN), instead of failing on an unbound metric."""
+    from repro.launch.train import main as train_main
+
+    common = ["--arch", "relic_tiny", "--smoke", "--batch", "2",
+              "--seq", "16", "--steps", "2", "--ckpt",
+              str(tmp_path / "ckpt"), "--ckpt-every", "2"]
+    assert np.isfinite(train_main(common))
+    assert np.isnan(train_main(common + ["--resume"]))
+    assert "resumed from step 2" in capsys.readouterr().out

@@ -176,3 +176,14 @@ def test_prefix_lm_mask(rng):
     # prefix positions attend bidirectionally: row 0 must differ from causal
     c = attention_full(q, k, v, causal=True)
     assert not np.allclose(np.asarray(a)[0, 0], np.asarray(c)[0, 0])
+
+
+def test_kernel_dispatch_refuses_unknown_backend(monkeypatch):
+    """Interpret mode is for the CPU only: another non-TPU backend raises
+    instead of silently running the kernel bodies in Python."""
+    assert ops._interpret() is True          # the CPU test backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="gpu"):
+        ops._interpret()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False

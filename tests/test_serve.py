@@ -458,16 +458,18 @@ def test_prefill_scan_matches_per_token_decode_loop():
 
     # Reference: the one-token-at-a-time teacher-forced loop.
     cache_ref = model.init_cache(batch, cache_len)
-    tok_ref = None
+    tok_ref = logits_ref = None
     for t in range(plen):
-        tok_ref, _, cache_ref = serve_step(
+        tok_ref, logits_ref, cache_ref = serve_step(
             params, cache_ref, prompts[:, t:t + 1], jnp.int32(t))
 
     # Scan prefill: one dispatch.
     cache_scan = model.init_cache(batch, cache_len)
-    tok_scan, cache_scan = prefill(params, cache_scan, prompts)
+    tok_scan, logits_scan, cache_scan = prefill(params, cache_scan, prompts)
 
     np.testing.assert_array_equal(np.asarray(tok_ref), np.asarray(tok_scan))
+    np.testing.assert_allclose(np.asarray(logits_ref), np.asarray(logits_scan),
+                               rtol=1e-6, atol=1e-6)
     # The caches must be functionally identical: decoding from both must
     # yield the same tokens at every subsequent step.
     for t in range(plen, plen + gen):

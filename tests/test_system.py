@@ -1,6 +1,11 @@
 """End-to-end system behaviour: train-to-convergence smoke, serve loop,
 sharding rules coverage."""
 
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -84,11 +89,8 @@ def test_input_specs_cover_all_cells():
 
 
 def test_fit_spec_divisibility():
-    # AbstractMesh: fit_spec only consults axis names/sizes, no devices
-    # needed (compat shim handles the 0.4.x AbstractMesh signature)
-    from repro.compat import abstract_mesh
-
-    mesh = abstract_mesh((2, 4), ("data", "model"))
+    # AbstractMesh: fit_spec only consults axis names/sizes, no devices needed
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     # 20 heads do not divide model=4*? -> drops axis
     spec = shd.fit_spec(mesh, [None, "model", None], (3, 20, 64))
     assert spec == jax.sharding.PartitionSpec(None, "model", None)
@@ -99,3 +101,47 @@ def test_fit_spec_divisibility():
     # batch=2 divides data(2) but not data*model(8): degrade to prefix
     spec = shd.fit_spec(mesh, [("data", "model"), None], (2, 8))
     assert spec == jax.sharding.PartitionSpec("data", None)
+
+
+def _compile_cache_child(tmp_path, env_dir=None):
+    """Run enable_compile_cache() in a fresh process (it changes
+    process-wide JAX config); returns (helper's dir, JAX's dir). With
+    ``env_dir`` set as JAX_COMPILATION_CACHE_DIR the child also compiles one
+    program; without it, it compiles nothing, so no test writes into the
+    checkout's cache."""
+    root = Path(__file__).resolve().parent.parent
+    lines = ["import jax, jax.numpy as jnp",
+             "from repro.runtime.config import enable_compile_cache",
+             "d = enable_compile_cache()"]
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(root / "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+        lines += [
+            "jax.config.update('jax_persistent_cache_min_compile_time_secs',"
+            " 0)",
+            "jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()"]
+    lines.append("print(d); print(jax.config.jax_compilation_cache_dir)")
+    r = subprocess.run([sys.executable, "-c", "\n".join(lines)], env=env,
+                       cwd=tmp_path, capture_output=True, text=True,
+                       timeout=120)
+    assert r.returncode == 0, r.stderr
+    return r.stdout.split()
+
+
+def test_compile_cache_follows_env_var(tmp_path):
+    cache = tmp_path / "cc"
+    helper_dir, jax_dir = _compile_cache_child(tmp_path, cache)
+    assert helper_dir == jax_dir == str(cache)
+    assert any(cache.iterdir())  # the compile was written there
+
+
+def test_compile_cache_default_is_one_fixed_ignored_path(tmp_path):
+    from repro.runtime.config import COMPILE_CACHE_DIR
+
+    root = Path(__file__).resolve().parent.parent
+    first = _compile_cache_child(tmp_path)
+    assert first == [str(root / ".jax_cache")] * 2
+    assert _compile_cache_child(tmp_path) == first  # no pid/time in it
+    assert COMPILE_CACHE_DIR == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
