@@ -1,0 +1,13 @@
+"""Share of the chip's bf16 peak that the traced train steps achieve while
+the device is busy: the operations the traced steps require (forward and
+backward, ``bench/flops.py``; recomputation under remat not counted) over
+(device busy time x peak from ``bench/peaks.json``)."""
+
+from bench.drivers.common import peaks
+
+
+def read(facts, trace):
+    if trace is None or not trace["busy_s"] or not facts.get("traced_flops"):
+        return None
+    peak = peaks(facts["device_kind"])["bf16_flops_per_s"]
+    return facts["traced_flops"] / (trace["busy_s"] * peak) * 100.0
