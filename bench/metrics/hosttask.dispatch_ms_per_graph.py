@@ -1,0 +1,13 @@
+"""Kernel dispatch time per task graph: the seconds of the traced window's
+``graph.dispatch`` spans (the host calling a jitted GAP kernel, on every
+thread) over its ``graph.run`` spans, in milliseconds."""
+
+from bench.spans import span_record
+
+
+def read(facts, trace):
+    runs, part = span_record(trace, "graph.run"), span_record(
+        trace, "graph.dispatch")
+    if not runs or not runs["count"] or part is None:
+        return None
+    return part["seconds"] / runs["count"] * 1e3

@@ -19,6 +19,7 @@ from typing import Callable, Iterator, Optional
 import numpy as np
 
 from repro.core.schedulers import Scheduler
+from repro.runtime.metrics import span
 from repro.stream import Pipeline, Stage, StreamFailure
 
 
@@ -175,7 +176,8 @@ class PrefetchPipeline:
         assert self._started, "call start() first"
         # Bounded wait: get_raw probes the producing stage's liveness and
         # raises RelicDeadError if its assistant died mid-stream.
-        batch = self._pipe.get_raw()
+        with span("data.wait"):
+            batch = self._pipe.get_raw()
         index = self._next_consume
         self._next_consume += 1
         # keep the assistant one window ahead

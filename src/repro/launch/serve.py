@@ -29,7 +29,8 @@ import numpy as np
 from repro.configs import get_config
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.models import build_model
-from repro.serve import ServeScheduler
+from repro.runtime.metrics import span
+from repro.serve import Request, ServeScheduler
 
 
 def load(cfg):
@@ -59,15 +60,23 @@ def serve(model, params, prompts, *, gen, cache_len, lanes=1, frames=None):
                                         encode(cfg, params, req_frames))
         return cache
 
-    def generate(prompt, req_frames):
-        tok, logits, cache = prefill(params, new_cache(req_frames), prompt)
+    def generate(rid, prompt, req_frames):
+        with span("serve.cache_init", rid=rid):
+            cache = new_cache(req_frames)
+        with span("serve.prefill", rid=rid):
+            tok, logits, cache = prefill(params, cache, prompt)
         # The prefill prediction is token 0; it is delivered once it exists,
         # so first_result_t (TTFT) covers the prefill, not its dispatch.
-        yield jax.block_until_ready(tok), logits
+        with span("serve.first_token", rid=rid):
+            jax.block_until_ready(tok)
+        yield tok, logits
         for t in range(plen, plen + gen - 1):
-            tok, logits, cache = serve_step(params, cache, tok, jnp.int32(t))
+            with span("serve.step", rid=rid, pos=t):
+                tok, logits, cache = serve_step(params, cache, tok,
+                                                jnp.int32(t))
             yield tok, logits
-        jax.block_until_ready(tok)
+        with span("serve.finish", rid=rid):
+            jax.block_until_ready(tok)
 
     # Warm both jits off the served path on one throwaway cache (both donate
     # their cache argument), so served requests measure steady-state steps,
@@ -79,8 +88,10 @@ def serve(model, params, prompts, *, gen, cache_len, lanes=1, frames=None):
 
     with ServeScheduler(lanes=lanes) as server:
         client = server.open_client("decode")
-        resps = [client.submit(generate, p, f)
-                 for p, f in zip(prompts, frames)]
+        resps = []
+        for p, f in zip(prompts, frames):
+            rid = Request.next_rid()
+            resps.append(client.submit(generate, rid, p, f, rid=rid))
         for resp in resps:
             assert len(resp.result()) == gen, (len(resp.result()), gen)
     return resps

@@ -18,15 +18,28 @@ to ``numpy.percentile(..., method="inverted_cdf")``, pinned against it by
 Nearest-rank always returns an *observed* sample, which is what an SLO
 report wants: "p99 = 4.1 ms" names a request that actually took 4.1 ms,
 not an interpolation between two that didn't.
+
+Program spans: ``span(name, **ids)`` marks one stretch of host work as a
+``jax.profiler.TraceAnnotation``, so it lands in a profiler trace on the
+same clock as the device's ops, its ``ids`` kept as the event's stats.
+Spans record exactly while a ``jax.profiler`` trace records this process
+(``TraceAnnotation.is_enabled()``); there is no switch of their own. At
+any other time a call returns one shared null context: a global read, the
+profiler's flag and a branch. ``jax`` is never imported here: where no one
+has imported it, no trace can run, so this module stays importable
+without it.
 """
 
 from __future__ import annotations
 
+import contextlib
 import math
+import sys
 from dataclasses import dataclass
 from typing import Dict, List, Sequence
 
-__all__ = ["nearest_rank", "percentiles", "LatencySeries", "Gauge"]
+__all__ = ["nearest_rank", "percentiles", "LatencySeries", "Gauge", "span",
+           "spans_enabled"]
 
 
 def nearest_rank(sorted_values: Sequence[float], q: float) -> float:
@@ -109,3 +122,30 @@ class Gauge:
             "last": self.last, "min": self.min,
             "max": self.max, "mean": self.mean,
         }
+
+
+# ------------------------------------------------------------ program spans
+
+_NULL_SPAN = contextlib.nullcontext()
+_annotation = None      # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def spans_enabled() -> bool:
+    """True while a ``jax.profiler`` trace records this process."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation.is_enabled()
+
+
+def span(name: str, /, **ids):
+    """A context manager marking ``name`` in the profiler trace, with
+    ``ids`` (ints or strings) as the event's stats; the shared null
+    context while no trace records."""
+    if not spans_enabled():
+        return _NULL_SPAN
+    return _annotation(name, **ids)

@@ -100,6 +100,7 @@ class ClientHandle:
         deadline_s: Optional[float] = None,
         must_admit: bool = False,
         idempotent: bool = False,
+        rid: Optional[int] = None,
     ) -> Optional[Response]:
         """Enqueue one request; returns its ``Response`` future.
 
@@ -112,7 +113,10 @@ class ClientHandle:
         would hang the client forever).
         ``deadline_s`` is seconds-from-now; defaults to the configured
         ``RELIC_SERVE_DEADLINE_MS``. ``idempotent=True`` marks the request
-        safe to re-run, opting it into server-side retry.
+        safe to re-run, opting it into server-side retry. ``rid`` is the
+        request's id, by default the next of ``Request.next_rid()``; a
+        caller that passes its own draws it from there too, so that its
+        work function can carry the id the server's spans carry.
         """
         self._check_producer()
         if self._closed:
@@ -122,7 +126,7 @@ class ClientHandle:
         if deadline_s is None:
             deadline_s = self._default_deadline_s
         req = Request(
-            rid=Request.next_rid(),
+            rid=Request.next_rid() if rid is None else rid,
             client_id=self.client_id,
             fn=fn,
             args=args,

@@ -50,8 +50,6 @@ class ServeMetrics:
     batch_occupancy: Gauge = field(default_factory=Gauge)
 
     latency: LatencySeries = field(default_factory=LatencySeries)
-    queue_delay: LatencySeries = field(default_factory=LatencySeries)
-    ttfr: LatencySeries = field(default_factory=LatencySeries)  # first result
 
     first_arrival_t: Optional[float] = None
     last_complete_t: Optional[float] = None
@@ -79,10 +77,6 @@ class ServeMetrics:
             if self.last_complete_t is None or t > self.last_complete_t:
                 self.last_complete_t = t
             self.latency.add(t - req.arrival_t)
-        if req.admit_t is not None:
-            self.queue_delay.add(req.admit_t - req.arrival_t)
-        if resp.first_result_t is not None:
-            self.ttfr.add(resp.first_result_t - req.arrival_t)
 
     @property
     def throughput(self) -> float:

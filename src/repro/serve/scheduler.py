@@ -62,6 +62,7 @@ from repro.runtime.config import (
     resolve_spin_pause_every,
     resolve_supervise_config,
 )
+from repro.runtime.metrics import span
 from repro.serve.ingest import ClientHandle, Ingest, ServeUsageError
 from repro.serve.metrics import ServeMetrics, now
 from repro.serve.request import (
@@ -221,6 +222,11 @@ class ServeScheduler:
     def _execute(self, resp: Response) -> None:
         """Run one request on a pool lane. Never raises: the Response is
         the error channel, so a failing request cannot poison the lane."""
+        req = resp.request
+        with span("serve.request", rid=req.rid):
+            self._run_request(resp)
+
+    def _run_request(self, resp: Response) -> None:
         req = resp.request
         first_t: Optional[float] = None
         try:

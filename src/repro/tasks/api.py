@@ -37,6 +37,7 @@ submit overheads, per-index tasks only make sense when the body itself is
 
 from __future__ import annotations
 
+import itertools
 import math
 import threading
 import time
@@ -44,6 +45,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple, Union
 
 from repro.core.schedulers import (USAGE_ERRORS, Scheduler,
                                    SchedulerUsageError, make_scheduler)
+from repro.runtime.metrics import span, spans_enabled
 
 __all__ = [
     "TaskScope",
@@ -54,6 +56,12 @@ __all__ = [
     "parallel_for",
     "map_reduce",
 ]
+
+
+# Ids that pair a task's ``task.submit`` and ``task.run`` spans, unique in
+# the process (scopes share them); drawn only while a profiler trace
+# records.
+_task_ids = itertools.count(1)
 
 
 class TaskGroupError(RuntimeError):
@@ -253,7 +261,12 @@ class TaskScope:
                      args: tuple, kwargs: dict) -> None:
         if self._closed:
             raise SchedulerUsageError("submit() on a closed TaskScope")
-        self._sched.submit(self._run_into, handle, fn, args, kwargs)
+        if not spans_enabled():
+            self._sched.submit(self._run_into, handle, fn, args, kwargs)
+            return
+        task = next(_task_ids)
+        with span("task.submit", task=task):
+            self._sched.submit(self._run_into, handle, fn, args, kwargs, task)
 
     def _submit_raw_many(self, tasks: List[tuple]) -> None:
         """Push pre-packed ``(fn, args, kwargs)`` tasks through the batch
@@ -268,9 +281,20 @@ class TaskScope:
                 self._sched.submit(fn, *args, **kwargs)
 
     def _run_into(self, handle: TaskHandle, fn: Callable[..., Any],
-                  args: tuple, kwargs: dict) -> None:
+                  args: tuple, kwargs: dict, task: Optional[int] = None) -> None:
         # Runs on a worker (or, for producer-participates, the owning
-        # thread). Exceptions are captured for the scope aggregate, so the
+        # thread). ``task`` is the id its ``task.submit`` span carried.
+        if not spans_enabled():
+            self._capture(handle, fn, args, kwargs)
+            return
+        if task is None:
+            task = next(_task_ids)
+        with span("task.run", task=task, name=str(handle.label)):
+            self._capture(handle, fn, args, kwargs)
+
+    def _capture(self, handle: TaskHandle, fn: Callable[..., Any],
+                 args: tuple, kwargs: dict) -> None:
+        # Exceptions are captured for the scope aggregate, so the
         # substrate's single-error channel stays empty.
         try:
             out = fn(*args, **kwargs)
@@ -621,8 +645,9 @@ class TaskGraph:
             if scope_kwargs:
                 raise TypeError("scope kwargs only apply when run() builds "
                                 "the TaskScope itself")
-            return runner(scope)
-        with TaskScope(scope, **scope_kwargs) as s:
+            with span("graph.run"):
+                return runner(scope)
+        with TaskScope(scope, **scope_kwargs) as s, span("graph.run"):
             return runner(s)
 
     def as_stream(self, scope: Union[TaskScope, str, Scheduler] = "relic",
@@ -635,8 +660,10 @@ class TaskGraph:
             node.handle._reset()
         remaining = dict(self._nodes)
         done: set = set()
+        wave_no = 0
         try:
             while remaining:
+                wave_no += 1
                 wave = [node for node in remaining.values()
                         if all(d in done for d in node.deps)]
                 # acyclic by construction => every round makes progress
@@ -652,7 +679,8 @@ class TaskGraph:
                 # raise — and clear — errors from unrelated sibling tasks,
                 # misattributing them to the graph (the same fix
                 # parallel_for has).
-                scope._wait_handles([node.handle for node in wave])
+                with span("graph.join", wave=wave_no):
+                    scope._wait_handles([node.handle for node in wave])
                 for node in wave:
                     done.add(node.name)
                     del remaining[node.name]
@@ -681,6 +709,7 @@ class TaskGraph:
         inflight: List[_Node] = []
         done: set = set()
         woke = False
+        wave_no = 0     # ready sets started so far
         try:
             while waiting or inflight:
                 progress = False
@@ -695,15 +724,17 @@ class TaskGraph:
                         # Join the graph's whole in-flight set and raise
                         # only its errors (pulled from the scope aggregate
                         # like the wavefront path's per-wave join).
-                        scope._wait_handles(
-                            [n.handle for n in finished]
-                            + [n.handle for n in still])
+                        with span("graph.join", wave=wave_no):
+                            scope._wait_handles(
+                                [n.handle for n in finished]
+                                + [n.handle for n in still])
                     for node in finished:
                         done.add(node.name)
                 ready = [node for node in waiting.values()
                          if all(d in done for d in node.deps)]
                 if ready:
                     progress = True
+                    wave_no += 1
                     for node in ready:
                         del waiting[node.name]
                     for node in ready[:-1]:
@@ -718,8 +749,9 @@ class TaskGraph:
                                  for d in last.deps)
                     scope._run_into(last.handle, last.fn, args, {})
                     if last.handle._error is not None:
-                        scope._wait_handles(
-                            [last.handle] + [n.handle for n in inflight])
+                        with span("graph.join", wave=wave_no):
+                            scope._wait_handles(
+                                [last.handle] + [n.handle for n in inflight])
                     done.add(last.name)
                 if progress:
                     woke = False
@@ -737,7 +769,8 @@ class TaskGraph:
                 if not woke:
                     scope.wake_up_hint()
                     woke = True
-                inflight[0].handle._wait(0.0005)
+                with span("graph.join", wave=wave_no):
+                    inflight[0].handle._wait(0.0005)
         finally:
             for node in waiting.values():
                 if not node.handle.done():

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime.metrics import span
+
 INF = jnp.float32(1e9)
 # Matvecs over non-0/1 values (rank, path counts, dependencies) run at f32
 # precision: the TPU's default rounds f32 matmul operands to bf16, beyond
@@ -241,30 +243,37 @@ def gap_task_graph(adj: jax.Array, w: jax.Array, source: int = 0):
     every kernel's output. Each task blocks on its device result so the
     scheduler measures real completion, not async dispatch. Run it with
     ``gap_task_graph(adj, w).run(scope_or_substrate)``.
+
+    Spans: ``graph.dispatch`` around each jitted call, ``graph.sync``
+    around its ``block_until_ready`` and around the summary's host reads.
     """
     from repro.tasks.api import TaskGraph
 
-    def done(x):
-        return jax.block_until_ready(x)
+    def done(name, kernel, *args):
+        with span("graph.dispatch", name=name):
+            out = kernel(*args)
+        with span("graph.sync", name=name):
+            return jax.block_until_ready(out)
+
+    def summary(b, c, pr, d, t, bc_):
+        with span("graph.sync", name="summary"):
+            return {
+                "reached": int((np.asarray(b) >= 0).sum()),
+                "components": int(len(np.unique(np.asarray(c)))),
+                "pr_mass": float(np.asarray(pr).sum()),
+                "finite_paths": int((np.asarray(d) < 1e8).sum()),
+                "triangles": float(t),
+                "max_bc": float(np.asarray(bc_).max()),
+            }
 
     g = TaskGraph()
-    g.task("bfs", lambda: done(bfs(adj, source)))
-    g.task("cc", lambda: done(connected_components(adj)))
-    g.task("pagerank", lambda: done(pagerank(adj)))
-    g.task("sssp", lambda: done(sssp(w, source)))
-    g.task("tc", lambda: done(triangle_count(adj)))
-    g.task("bc", lambda _bfs: done(betweenness_centrality(adj, source)),
+    g.task("bfs", lambda: done("bfs", bfs, adj, source))
+    g.task("cc", lambda: done("cc", connected_components, adj))
+    g.task("pagerank", lambda: done("pagerank", pagerank, adj))
+    g.task("sssp", lambda: done("sssp", sssp, w, source))
+    g.task("tc", lambda: done("tc", triangle_count, adj))
+    g.task("bc", lambda _bfs: done("bc", betweenness_centrality, adj, source),
            deps=("bfs",))
-    g.task(
-        "summary",
-        lambda b, c, pr, d, t, bc_: {
-            "reached": int((np.asarray(b) >= 0).sum()),
-            "components": int(len(np.unique(np.asarray(c)))),
-            "pr_mass": float(np.asarray(pr).sum()),
-            "finite_paths": int((np.asarray(d) < 1e8).sum()),
-            "triangles": float(t),
-            "max_bc": float(np.asarray(bc_).max()),
-        },
-        deps=("bfs", "cc", "pagerank", "sssp", "tc", "bc"),
-    )
+    g.task("summary", summary,
+           deps=("bfs", "cc", "pagerank", "sssp", "tc", "bc"))
     return g
