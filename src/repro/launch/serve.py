@@ -6,7 +6,8 @@ The demo form of the serving stack (docs/serving.md): each request is a
 ``(tokens, logits)`` item, so the response's ``first_result_t`` is the
 time-to-first-token and the subsystem's latency accounting applies unchanged
 to token serving. One loaded model answers any number of requests; each
-request allocates its own cache.
+request gets its own cache, which the programs update in place
+(``cache_programs``).
 
 Prefill is ``make_prefill_step`` — one jitted ``lax.scan`` dispatch over
 the prompt positions instead of O(prompt_len) ``serve_step`` dispatches
@@ -21,6 +22,8 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -40,29 +43,56 @@ def load(cfg):
     return model, jax.jit(model.init)(jax.random.PRNGKey(0))
 
 
+def cache_programs(model, params, batch, cache_len):
+    """The programs a request runs, around one cache buffer.
+
+    A jitted program takes and returns the cache in its device's default
+    layout. The decode step and the prefill hold the cache in that layout
+    inside their layer loops too (``decode_step``'s ``cache_layout``), so
+    both write their new entries into the donated buffer in place; left
+    free, the compiler gives the loop a layout of its own and copies the
+    whole cache into and out of it on every step. ``new_cache`` makes each
+    request's cache on the device (for enc-dec models with the encoder's
+    cross-attention entries filled in). ``params`` may be arrays or
+    ``ShapeDtypeStruct``s with shardings; the cache lives where they do.
+    Returns ``(new_cache(params, frames), prefill, step)``, jitted; the
+    prefill and the step donate the cache."""
+    cfg = model.cfg
+    sharding = jax.tree.leaves(params)[0].sharding
+    cache = jax.eval_shape(lambda: model.init_cache(batch, cache_len))
+    formats = jax.jit(lambda c: c, in_shardings=sharding).lower(
+        cache).compile().input_formats[0][0]
+    held = dataclasses.replace(model, decode_step=functools.partial(
+        model.decode_step,
+        cache_layout=jax.tree.map(lambda f: f.layout, formats)))
+
+    def init(params, frames):
+        cache = model.init_cache(batch, cache_len)
+        if cfg.family == "encdec":
+            from repro.models.encdec import encode, prefill_cross_cache
+            cache = prefill_cross_cache(cfg, params, cache,
+                                        encode(cfg, params, frames))
+        return cache
+
+    return (jax.jit(init),
+            jax.jit(make_prefill_step(held), donate_argnums=(1,)),
+            jax.jit(make_serve_step(held), donate_argnums=(1,)))
+
+
 def serve(model, params, prompts, *, gen, cache_len, lanes=1, frames=None):
     """Answer one request per ``[B, P]`` array in ``prompts`` on one loaded
     model. Returns the finished ``Response`` objects; each one's result is
     ``gen`` items of ``(tokens [B, 1], logits [B, 1, V])``: the prefill's
     prediction, then one per decode step. ``frames`` (enc-dec only) holds
     each request's encoder input."""
-    cfg = model.cfg
     batch, plen = prompts[0].shape
     frames = frames or [None] * len(prompts)
-    serve_step = jax.jit(make_serve_step(model), donate_argnums=(1,))
-    prefill = jax.jit(make_prefill_step(model), donate_argnums=(1,))
-
-    def new_cache(req_frames):
-        cache = model.init_cache(batch, cache_len)
-        if cfg.family == "encdec":
-            from repro.models.encdec import encode, prefill_cross_cache
-            cache = prefill_cross_cache(cfg, params, cache,
-                                        encode(cfg, params, req_frames))
-        return cache
+    new_cache, prefill, serve_step = cache_programs(model, params, batch,
+                                                    cache_len)
 
     def generate(rid, prompt, req_frames):
         with span("serve.cache_init", rid=rid):
-            cache = new_cache(req_frames)
+            cache = new_cache(params, req_frames)
         with span("serve.prefill", rid=rid):
             tok, logits, cache = prefill(params, cache, prompt)
         # The prefill prediction is token 0; it is delivered once it exists,
@@ -78,10 +108,10 @@ def serve(model, params, prompts, *, gen, cache_len, lanes=1, frames=None):
         with span("serve.finish", rid=rid):
             jax.block_until_ready(tok)
 
-    # Warm both jits off the served path on one throwaway cache (both donate
-    # their cache argument), so served requests measure steady-state steps,
-    # not compilation.
-    tok, _, cache = prefill(params, new_cache(frames[0]),
+    # Warm every program off the served path on one throwaway cache (the
+    # prefill and the step donate it), so served requests measure
+    # steady-state steps, not compilation.
+    tok, _, cache = prefill(params, new_cache(params, frames[0]),
                             jnp.zeros_like(prompts[0]))
     jax.block_until_ready(serve_step(params, cache, tok, jnp.int32(plen))[0])
     del cache

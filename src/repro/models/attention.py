@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import _normal, apply_rope, dt, rms_norm_headwise
+from repro.models.layers import (_normal, apply_rope, dt, layer_entry,
+                                 rms_norm_headwise, write_layer)
 from repro.sharding import shard_act
 
 NEG_INF = -1e30
@@ -289,21 +290,34 @@ def decode_self_attention(
     x: jax.Array,           # [B,1,D]
     cache: dict,            # {"k": [B,T,Kv,Dh], "v": [B,T,Kv,Dh]}
     pos: jax.Array,         # [] int32 current position
+    layer: Optional[jax.Array] = None,
 ):
-    """One-token decode against a fixed-length KV cache; returns (y, cache)."""
+    """One-token decode against a fixed-length KV cache; returns (y, cache).
+
+    With ``layer``, ``cache`` holds every layer's K/V stacked
+    ([L,B,T,Kv,Dh]): only row ``(layer, :, pos)`` is written, and the layer's
+    slab is read in place, so a step that carries the stack through its
+    layer loop never copies it."""
     q, k_new, v_new = _project_qkv(cfg, p, x)
     if cfg.use_rope:
         posb = jnp.broadcast_to(pos, (x.shape[0], 1))
         q = apply_rope(q, posb, cfg.rope_theta)
         k_new = apply_rope(k_new, posb, cfg.rope_theta)
-    zero = jnp.int32(0)
-    k = jax.lax.dynamic_update_slice(cache["k"], k_new.astype(cache["k"].dtype),
-                                     (zero, pos.astype(jnp.int32), zero, zero))
-    v = jax.lax.dynamic_update_slice(cache["v"], v_new.astype(cache["v"].dtype),
-                                     (zero, pos.astype(jnp.int32), zero, zero))
+    pos = pos.astype(jnp.int32)
+    new = {"k": k_new, "v": v_new}
+    if layer is None:
+        zero = jnp.int32(0)
+        cache = jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_slice(
+                a, n.astype(a.dtype), (zero, pos, zero, zero)), cache, new)
+        k, v = cache["k"], cache["v"]
+    else:
+        cache = write_layer(cache, new, layer, pos)
+        slab = layer_entry(cache, layer)
+        k, v = slab["k"], slab["v"]
     o = attention_core(cfg, q, k, v, causal=False, kv_len=pos + 1)
     y = _output(cfg, p, o)
-    return y, {"k": k, "v": v}
+    return y, cache
 
 
 def decode_cross_attention(

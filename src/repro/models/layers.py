@@ -173,3 +173,30 @@ def mlp(cfg: ModelConfig, p, x: jax.Array) -> jax.Array:
     else:
         y = h @ p["w_down"].astype(cd)
     return shard_act(y, "batch", None, "model", kind="resid")
+
+
+# ---------------------------------------------------------------------------
+# Stacked decode caches: every layer's entry in one [L, ...] array per leaf
+# ---------------------------------------------------------------------------
+
+def layer_entry(stacked, layer):
+    """Layer ``layer``'s entry of a stacked cache, read by dynamic index."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+        stacked)
+
+
+def write_layer(stacked, new, layer, pos):
+    """Write one layer's new cache entries into the stacked cache in place.
+
+    A leaf of ``new`` with the shape of the layer's whole entry (a recurrent
+    state) is written whole; a shorter one is a run along the time axis
+    (axis 1, after batch: an attention row) and is written at time ``pos``.
+    """
+    def put(a, n):
+        at = [layer] + [0] * n.ndim
+        if n.shape != a.shape[1:]:
+            at[2] = pos
+        return jax.lax.dynamic_update_slice(a, n[None].astype(a.dtype), at)
+
+    return jax.tree.map(put, stacked, new)

@@ -2,8 +2,8 @@
 
 Layers are **scanned** (`lax.scan` over stacked params) so that HLO size and
 compile time are O(1) in depth — required for 126-layer dry-runs — with a
-configurable remat policy. Decode threads per-layer caches through the same
-scans.
+configurable remat policy. Decode carries the stacked per-layer cache
+through the same scans and writes each layer's new entries into it in place.
 """
 
 from __future__ import annotations
@@ -13,6 +13,7 @@ from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import with_layout_constraint
 
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
@@ -25,7 +26,8 @@ from repro.sharding import shard_act
 
 # ---------------------------------------------------------------------------
 # Per-family blocks.  Every block is  (cfg, params, x, **kw) -> (x, aux)
-# and has a decode twin  (cfg, params, x, cache, pos) -> (x, cache, aux).
+# and has a decode twin  (cfg, params, x, cache, layer, pos) -> (x, cache)
+# over the stacked cache of every layer.
 # ---------------------------------------------------------------------------
 
 def init_block(cfg: ModelConfig, key):
@@ -130,12 +132,15 @@ def init_block_cache(cfg: ModelConfig, batch: int, cache_len: int):
     raise ValueError(cfg.family)
 
 
-def block_decode(cfg: ModelConfig, p, x, cache, pos):
-    """Returns (x, cache)."""
+def block_decode(cfg: ModelConfig, p, x, cache, layer, pos):
+    """One layer's decode step against the stacked cache of every layer
+    (``{"cache": ...}`` with ``[L, ...]`` leaves), writing only this
+    layer's new entries in place. Returns (x, cache)."""
     c = cache["cache"]
     if cfg.family in ("dense", "vlm", "moe"):
         y, c = attn.decode_self_attention(cfg, p["attn"],
-                                          L.norm(cfg, p["ln1"], x), c, pos)
+                                          L.norm(cfg, p["ln1"], x), c, pos,
+                                          layer)
         x = x + y
         if cfg.family == "moe":
             y, _ = moe_mod.moe_ffn(cfg, p["moe"], L.norm(cfg, p["ln2"], x))
@@ -143,29 +148,33 @@ def block_decode(cfg: ModelConfig, p, x, cache, pos):
             y = L.mlp(cfg, p["mlp"], L.norm(cfg, p["ln2"], x))
         x = x + y
     elif cfg.family == "ssm":
+        s = L.layer_entry(c, layer)
         xn = L.norm(cfg, p["ln1"], x)
         y, tc = r6.rwkv_time_mix_decode(cfg, p["rwkv"], xn,
-                                        {"shift_state": c["shift_state"],
-                                         "wkv_state": c["wkv_state"]})
+                                        {"shift_state": s["shift_state"],
+                                         "wkv_state": s["wkv_state"]})
         x = x + y
         xn2 = L.norm(cfg, p["ln2"], x)
         y2 = r6.rwkv_channel_mix(cfg, p["cmix"], xn2,
-                                 shift_state=c["cmix_shift_state"])
+                                 shift_state=s["cmix_shift_state"])
         x = x + y2
-        c = {"shift_state": tc["shift_state"], "wkv_state": tc["wkv_state"],
-             "cmix_shift_state": xn2[:, 0]}
+        c = L.write_layer(c, {"shift_state": tc["shift_state"],
+                              "wkv_state": tc["wkv_state"],
+                              "cmix_shift_state": xn2[:, 0]}, layer, pos)
     elif cfg.family == "hybrid":
-        y, c = m2.mamba2_block_decode(cfg, p["ssm"], L.norm(cfg, p["ln"], x), c)
+        y, s = m2.mamba2_block_decode(cfg, p["ssm"], L.norm(cfg, p["ln"], x),
+                                      L.layer_entry(c, layer))
         x = x + y
+        c = L.write_layer(c, s, layer, pos)
     else:
         raise ValueError(cfg.family)
     return x, {"cache": c}
 
 
-def shared_attn_decode(cfg: ModelConfig, p, x, kv_cache, pos):
+def shared_attn_decode(cfg: ModelConfig, p, x, kv_cache, layer, pos):
     y, kv_cache = attn.decode_self_attention(cfg, p["attn"],
                                              L.norm(cfg, p["ln1"], x),
-                                             kv_cache, pos)
+                                             kv_cache, pos, layer)
     x = x + y
     x = x + L.mlp(cfg, p["mlp"], L.norm(cfg, p["ln2"], x))
     return x, kv_cache
@@ -358,72 +367,74 @@ def init_lm_cache(cfg: ModelConfig, batch: int, cache_len: int):
     return out
 
 
+def _keep_layout(cache, layout):
+    """``cache`` held in ``layout``; with ``None``, where the compiler puts it."""
+    return cache if layout is None else with_layout_constraint(cache, layout)
+
+
+def _decode_layers(cfg: ModelConfig, layers_p, x, cache, first, pos, layout):
+    """Decode through stacked layers ``layers_p`` (``[n, ...]``), layer
+    ``first`` onwards, carrying the whole stacked cache through the loop so
+    each layer writes its entries into it in place. Returns (x, cache)."""
+    n = jax.tree.leaves(layers_p)[0].shape[0]
+
+    def body(carry, inp):
+        x, cache = carry
+        lp, layer = inp
+        x, cache = block_decode(cfg, lp, x, cache, layer, pos)
+        return (x, _keep_layout(cache, layout)), None
+
+    (x, cache), _ = maybe_scan(cfg, body, (x, cache),
+                               (layers_p, first + jnp.arange(n)))
+    return x, cache
+
+
 def lm_decode_step(cfg: ModelConfig, params, cache: dict, tokens: jax.Array,
-                   pos: jax.Array):
-    """One decode step. tokens: [B,1]; pos: [] -> (logits [B,1,V], cache)."""
+                   pos: jax.Array, cache_layout=None):
+    """One decode step. tokens: [B,1]; pos: [] -> (logits [B,1,V], cache).
+
+    Every family runs one loop: groups of ``attn_every`` layers, each
+    followed by the shared attention block (zamba2), then the remaining
+    layers (all of them where no block is shared). The cache goes through
+    the loops as their carry, never as scanned inputs and outputs, and
+    each layer writes only its new entries.
+
+    ``cache_layout`` (a ``Layout`` per leaf of ``cache``) holds the carried
+    cache in that layout: given the layout of the step's cache argument,
+    the step reads and writes the caller's (donated) buffer in place. Left
+    free, a compiler may give the loop a layout of its own and copy the
+    whole cache into and out of it on every step."""
     x = L.embed(cfg, params["embed"], tokens)
     if cfg.family == "vlm":
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
 
-    blk = functools.partial(block_decode, cfg)
+    layout = cache_layout or {}
+    full, tail = _hybrid_groups(cfg)
+    k = cfg.attn_every
+    layers_p, lc = params["layers"], cache["layers"]
+    sac = cache.get("shared_attn")
+    if full:
+        gp = jax.tree.map(lambda a: a[: full * k].reshape(full, k, *a.shape[1:]),
+                          layers_p)
 
-    if cfg.family == "hybrid":
-        full, tail = _hybrid_groups(cfg)
-        k = cfg.attn_every
-        layers_p, layer_c = params["layers"], cache["layers"]
-        new_cache = {"layers": None, "shared_attn": None}
-        if full:
-            gp = jax.tree.map(lambda a: a[: full * k].reshape(full, k, *a.shape[1:]),
-                              layers_p)
-            gc = jax.tree.map(lambda a: a[: full * k].reshape(full, k, *a.shape[1:]),
-                              layer_c)
+        def group_body(carry, inp):
+            x, lc, sac = carry
+            g_p, g = inp
+            x, lc = _decode_layers(cfg, g_p, x, lc, g * k, pos,
+                                   layout.get("layers"))
+            x, sac = shared_attn_decode(cfg, params["shared_attn"], x, sac,
+                                        g, pos)
+            return (x, lc, _keep_layout(sac, layout.get("shared_attn"))), None
 
-            def group_body(x, inp):
-                g_p, g_c, sa_c = inp
-
-                def inner(x_, inp_):
-                    lp, lc = inp_
-                    x_, nc = blk(lp, x_, lc, pos)
-                    return x_, nc
-
-                x, g_c_new = maybe_scan(cfg, inner, x, (g_p, g_c))
-                x, sa_c_new = shared_attn_decode(cfg, params["shared_attn"], x,
-                                                 sa_c, pos)
-                return x, (g_c_new, sa_c_new)
-
-            x, (gc_new, sac_new) = maybe_scan(
-                cfg, group_body, x, (gp, gc, cache["shared_attn"]))
-            gc_new = jax.tree.map(
-                lambda a: a.reshape(full * k, *a.shape[2:]), gc_new)
-        else:
-            gc_new, sac_new = None, cache.get("shared_attn")
-        if tail:
-            tp = jax.tree.map(lambda a: a[full * k:], layers_p)
-            tc = jax.tree.map(lambda a: a[full * k:], layer_c)
-
-            def inner(x_, inp_):
-                lp, lc = inp_
-                x_, nc = blk(lp, x_, lc, pos)
-                return x_, nc
-
-            x, tc_new = maybe_scan(cfg, inner, x, (tp, tc))
-            lc_new = (jax.tree.map(lambda a, b: jnp.concatenate([a, b], 0),
-                                   gc_new, tc_new)
-                      if gc_new is not None else tc_new)
-        else:
-            lc_new = gc_new
-        new_cache = {"layers": lc_new}
-        if sac_new is not None:
-            new_cache["shared_attn"] = sac_new
-    else:
-        def body(x, inp):
-            lp, lc = inp
-            x, nc = blk(lp, x, lc, pos)
-            return x, nc
-
-        x, lc_new = maybe_scan(cfg, body, x,
-                               (params["layers"], cache["layers"]))
-        new_cache = {"layers": lc_new}
+        (x, lc, sac), _ = maybe_scan(cfg, group_body, (x, lc, sac),
+                                     (gp, jnp.arange(full)))
+        layers_p = jax.tree.map(lambda a: a[full * k:], layers_p)
+    if tail:
+        x, lc = _decode_layers(cfg, layers_p, x, lc, full * k, pos,
+                               layout.get("layers"))
+    new_cache = {"layers": lc}
+    if sac is not None:
+        new_cache["shared_attn"] = sac
 
     x = L.norm(cfg, params["final_norm"], x)
     tied = params["embed"]["table"] if cfg.tie_embeddings else None

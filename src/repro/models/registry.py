@@ -6,7 +6,7 @@ the launcher, dry-run, tests, and benchmarks need:
   init(key) -> params
   loss(params, batch) -> (scalar, metrics)            # train step objective
   init_cache(batch, cache_len) -> cache               # decode state
-  decode_step(params, cache, tokens, pos) -> (logits, cache)
+  decode_step(params, cache, tokens, pos[, cache_layout]) -> (logits, cache)
   input_specs(shape) -> (batch_pytree of ShapeDtypeStruct, cache_len | None)
 """
 
@@ -78,8 +78,9 @@ def build_model(cfg: ModelConfig) -> Model:
             loss=lambda p, b: ed.encdec_loss(cfg, p, b),
             init_cache=lambda batch, cache_len: ed.init_encdec_cache(
                 cfg, batch, cache_len),
-            decode_step=lambda p, c, t, pos: ed.encdec_decode_step(
-                cfg, p, c, t, pos),
+            # The decoder scans its per-layer caches; no layout to hold.
+            decode_step=lambda p, c, t, pos, cache_layout=None: (
+                ed.encdec_decode_step(cfg, p, c, t, pos)),
             input_specs=lambda shape: _encdec_input_specs(cfg, shape),
         )
     return Model(
@@ -88,6 +89,7 @@ def build_model(cfg: ModelConfig) -> Model:
         loss=lambda p, b: lm.lm_loss(cfg, p, b),
         init_cache=lambda batch, cache_len: lm.init_lm_cache(
             cfg, batch, cache_len),
-        decode_step=lambda p, c, t, pos: lm.lm_decode_step(cfg, p, c, t, pos),
+        decode_step=lambda p, c, t, pos, cache_layout=None: lm.lm_decode_step(
+            cfg, p, c, t, pos, cache_layout),
         input_specs=lambda shape: _lm_input_specs(cfg, shape),
     )

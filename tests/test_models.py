@@ -123,6 +123,45 @@ def test_decode_matches_teacher_forcing(arch, rng):
     assert agree > 0.9, (arch, agree)
 
 
+@pytest.mark.parametrize("arch", ["relic_tiny", "arctic_480b",
+                                  "zamba2_1p2b"],
+                         ids=["dense", "moe", "hybrid"])
+def test_decode_step_writes_only_row_pos_of_each_kv_cache(arch, rng):
+    """One decode step changes row ``pos`` of every layer's K and V and
+    leaves every other cache element bit-equal to the input cache (the
+    hybrid's K/V are its shared attention block's)."""
+    cfg = get_config(arch, smoke=True)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(4))
+    b, t, pos = 2, 8, 5
+    cache = jax.tree.map(
+        lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype),
+        model.init_cache(b, t))
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (b, 1)), jnp.int32)
+    _, new = jax.jit(model.decode_step)(params, cache, toks, jnp.int32(pos))
+
+    pairs = [(cache["layers"]["cache"], new["layers"]["cache"])]
+    if "shared_attn" in cache:
+        pairs.append((cache["shared_attn"], new["shared_attn"]))
+    rest = np.arange(t) != pos
+    checked = 0
+    for old_c, new_c in pairs:
+        for name in ("k", "v"):
+            if name not in old_c:
+                continue
+            before = np.asarray(old_c[name], np.float32)   # [L,B,T,Kv,Dh]
+            after = np.asarray(new_c[name], np.float32)
+            np.testing.assert_array_equal(after[:, :, rest],
+                                          before[:, :, rest])
+            # every layer's row pos is rewritten
+            assert (after[:, :, pos] != before[:, :, pos]).any(
+                axis=(1, 2, 3)).all()
+            checked += 1
+    assert checked == 2, arch
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), new) == jax.tree.map(
+        lambda a: (a.shape, a.dtype), cache)
+
+
 def test_encdec_decode_matches_teacher_forcing(rng):
     cfg = get_config("whisper_large_v3", smoke=True)
     model = build_model(cfg)

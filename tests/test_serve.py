@@ -481,6 +481,47 @@ def test_prefill_scan_matches_per_token_decode_loop():
             np.asarray(tok_ref), np.asarray(tok_scan))
 
 
+@pytest.mark.parametrize("arch", ["relic_tiny", "arctic_480b", "rwkv6_1p6b",
+                                  "zamba2_1p2b"],
+                         ids=["dense", "moe", "ssm", "hybrid"])
+def test_serve_programs_match_plain_jits(arch):
+    """Prefill then decode through serve()'s programs (the cache made by
+    their jitted init, held in its layout through the layer loops, donated)
+    yields the same tokens and logits as plain jits of the same steps."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.launch.serve import cache_programs
+    from repro.launch.steps import make_prefill_step, make_serve_step
+    from repro.models import build_model
+
+    model = build_model(get_config(arch, smoke=True))
+    params = model.init(jax.random.PRNGKey(0))
+    batch, plen, gen = 2, 4, 4
+    cache_len = plen + gen
+    prompts = jnp.asarray(np.random.default_rng(2).integers(
+        0, model.cfg.vocab_size, (batch, plen)), jnp.int32)
+
+    def run(new_cache, prefill, step):
+        tok, logits, cache = prefill(params, new_cache(), prompts)
+        out = [(tok, logits)]
+        for t in range(plen, plen + gen - 1):
+            tok, logits, cache = step(params, cache, tok, jnp.int32(t))
+            out.append((tok, logits))
+        return out
+
+    new_cache, prefill, step = cache_programs(model, params, batch, cache_len)
+    served = run(lambda: new_cache(params, None), prefill, step)
+    plain = run(lambda: model.init_cache(batch, cache_len),
+                jax.jit(make_prefill_step(model)),
+                jax.jit(make_serve_step(model)))
+    for (tok_s, logits_s), (tok_p, logits_p) in zip(served, plain):
+        np.testing.assert_array_equal(np.asarray(tok_s), np.asarray(tok_p))
+        np.testing.assert_array_equal(np.asarray(logits_s),
+                                      np.asarray(logits_p))
+
+
 # ---------------------------------------------------------------------------
 # benchmarks section registry (satellite tripwire)
 # ---------------------------------------------------------------------------

@@ -13,6 +13,8 @@ in this one file.
 """
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +32,8 @@ from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.relic_matmul import relic_matmul, relic_matmul_gated
 from repro.kernels.ssd import ssd_bhtp
 from repro.kernels.wkv6 import wkv6_bhtk
-from repro.launch.steps import (make_prefill_step, make_serve_step,
-                                make_train_state, make_train_step)
+from repro.launch.serve import cache_programs
+from repro.launch.steps import make_train_state, make_train_step
 from repro.models import build_model
 from repro.optim import OptConfig
 
@@ -123,28 +125,66 @@ def test_ssd_compiles_at_zamba2_widths(one_chip):
     assert _is_kernel(_compile(fn, x, a, bc, bc))
 
 
+def _phi3_serve_programs(one_chip, step, batch, cache_len, prompt_len):
+    """phi3-mini's prefill or decode step at full width, compiled as
+    ``serve()`` compiles them: the cache held in its default layout through
+    the layer loops, and donated."""
+    cfg = get_config("phi3_mini_3p8b").replace(param_dtype="bfloat16")
+    model = build_model(cfg)
+    params = _placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                     one_chip)
+    _, prefill, serve_step = cache_programs(model, params, batch, cache_len)
+    cache = _placed(jax.eval_shape(lambda: model.init_cache(batch, cache_len)),
+                    one_chip)
+    if step == "serve_step":
+        fn, args = serve_step, (_sds((batch, 1), jnp.int32, one_chip),
+                                _sds((), jnp.int32, one_chip))
+    else:
+        fn, args = prefill, (_sds((batch, prompt_len), jnp.int32, one_chip),)
+    return fn.lower(params, cache, *args).compile()
+
+
 @pytest.mark.parametrize("step", ["prefill", "serve_step"])
 def test_phi3_serve_fits_one_chip(one_chip, step):
     """chip_smoke's serve phase at full phi3-mini width must fit one chip's
-    HBM (the scan-of-decode prefill holds ~4x its cache in temporaries)."""
-    cfg = get_config("phi3_mini_3p8b").replace(param_dtype="bfloat16")
-    model = build_model(cfg)
-    b, cache_len = chip_smoke.SERVE_BATCH, chip_smoke.SERVE_CACHE_LEN
-    params = _placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
-                     one_chip)
-    cache = _placed(jax.eval_shape(lambda: model.init_cache(b, cache_len)),
-                    one_chip)
-    if step == "prefill":
-        fn = make_prefill_step(model)
-        args = (_sds((b, chip_smoke.PROMPT_LEN), jnp.int32, one_chip),)
-    else:
-        fn = make_serve_step(model)
-        args = (_sds((b, 1), jnp.int32, one_chip),
-                _sds((), jnp.int32, one_chip))
-    ma = _compile(fn, params, cache, *args,
-                  donate_argnums=(1,)).memory_analysis()
+    HBM."""
+    ma = _phi3_serve_programs(
+        one_chip, step, chip_smoke.SERVE_BATCH, chip_smoke.SERVE_CACHE_LEN,
+        chip_smoke.PROMPT_LEN).memory_analysis()
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
     assert used < HBM_BUDGET, used / GiB
+
+
+_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
+          "u8": 1, "pred": 1}
+
+
+def _copies_of_at_least(text, nbytes):
+    """The ``copy`` instructions of a compiled program's HLO whose result
+    holds at least ``nbytes``, as (name, shape)."""
+    found = []
+    for m in re.finditer(r"(%\S+) = (\w+)\[([\d,]*)\]\S* copy(?:-start)?\(",
+                         text):
+        size = _BYTES[m.group(2)] * math.prod(
+            int(d) for d in m.group(3).split(",") if d)
+        if size >= nbytes:
+            found.append((m.group(1), f"{m.group(2)}[{m.group(3)}]"))
+    return found
+
+
+@pytest.mark.parametrize("step", ["prefill", "serve_step"])
+def test_phi3_serve_keeps_the_cache_in_place(one_chip, step):
+    """At the benchmark's serving sizes (batch 4, cache 512, prompt 64) the
+    programs serve() builds copy no cache-sized array: the donated cache is
+    the one buffer they read and write, in one layout. One layer's K slab
+    is the smallest cache-sized array."""
+    batch, cache_len, prompt_len = 4, 512, 64
+    compiled = _phi3_serve_programs(one_chip, step, batch, cache_len,
+                                    prompt_len)
+    cfg = get_config("phi3_mini_3p8b")
+    slab = batch * cache_len * cfg.n_kv_heads * cfg.resolved_head_dim * 2
+    assert _copies_of_at_least(compiled.as_text(), slab) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
 def test_relic_tiny_train_step_fits_one_chip(one_chip):
