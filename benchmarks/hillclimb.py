@@ -67,10 +67,6 @@ def apply_variant(cfg, variant: str):
         return cfg.replace(mlp_tp_overlap=True, param_dtype="bfloat16")
     if variant == "attn_big":      # memory-bound: bigger attention tiles
         return cfg.replace(attn_chunk=4096, attn_chunk_q=2048)
-    if variant == "cap1":          # MoE: capacity factor 1.0 (smaller buffers)
-        import dataclasses
-        return cfg.replace(moe=dataclasses.replace(cfg.moe,
-                                                   capacity_factor=1.0))
     if variant == "remat_dots":    # save matmul outputs, recompute elementwise
         return cfg.replace(remat="dots")
     if variant == "attn_big_ring":
@@ -93,11 +89,6 @@ def apply_variant(cfg, variant: str):
     if variant == "repl_skip":
         shd.set_activation_layout("replicated")
         return cfg.replace(causal_skip=True)
-    if variant == "cap1_skip":
-        import dataclasses
-        return cfg.replace(causal_skip=True,
-                           moe=dataclasses.replace(cfg.moe,
-                                                   capacity_factor=1.0))
     if variant == "repl_dots":
         shd.set_activation_layout("replicated")
         return cfg.replace(remat="dots")

@@ -5,10 +5,11 @@ calls, with random weights from seed 0:
 
   serve     phi3-mini-3.8B at its full published width (bf16 params) answers
             3 requests of 128 prompt + 32 generated tokens through
-            ``repro.launch.serve`` and ``ServeScheduler``. Check: the cached
-            path's logits agree with the teacher-forced ``lm_forward`` over
-            prompt + generated tokens, at the last prompt position and at
-            every generated position.
+            ``repro.launch.serve`` and ``ServeScheduler``. Check: the logit
+            served with each token agrees with the teacher-forced
+            ``lm_forward``'s logit of that token over prompt + generated
+            tokens, at the last prompt position and at every generated
+            position.
   train     relic_tiny at its full config takes 20 steps through
             ``repro.launch.train.main`` (Relic-prefetched data, Relic
             checkpoint writer, a save every 10 steps), then resumes to step
@@ -137,9 +138,11 @@ def serve_phase(cfg, *, batch: int, prompt_len: int, gen: int,
     for prompt, resp in zip(prompts, resps):
         out = resp.result()
         toks = jnp.concatenate([t for t, _ in out], axis=1)       # [B, gen]
-        cached = jnp.concatenate([lg for _, lg in out], axis=1)   # [B, gen, V]
+        cached = jnp.concatenate([lg for _, lg in out], axis=1)   # [B, gen]
         ref, _ = forward(params, jnp.concatenate([prompt, toks[:, :-1]], 1))
-        ref = ref[:, prompt_len - 1:]
+        # the forward pass's logits of the served tokens
+        ref = jnp.take_along_axis(ref[:, prompt_len - 1:], toks[..., None],
+                                  -1)[..., 0]
         err = jnp.abs(cached - ref)
         excess.append(float(jnp.max(err - LOGIT_ATOL
                                     - LOGIT_RTOL * jnp.abs(ref))))

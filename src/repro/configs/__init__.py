@@ -1,4 +1,5 @@
-"""Architecture registry: the ten assigned configs + the paper-scale tiny LM.
+"""Architecture registry: the assigned configs, DeepSeek-V3, and the
+paper-scale tiny LM.
 
 Each module exports CONFIG (the exact assigned full config) and SMOKE (a
 reduced same-family config for CPU smoke tests). Full configs are only ever
@@ -11,12 +12,14 @@ import importlib
 
 from repro.configs.base import (  # noqa: F401
     FrontendConfig,
+    MLAConfig,
     ModelConfig,
     MoEConfig,
     SHAPES,
     ShapeConfig,
     SSMConfig,
     shape_applicable,
+    YaRNConfig,
 )
 
 ARCH_IDS = [
@@ -30,6 +33,7 @@ ARCH_IDS = [
     "rwkv6_1p6b",
     "zamba2_1p2b",
     "paligemma_3b",
+    "deepseek_v3",
     "relic_tiny",      # paper-scale end-to-end example config
 ]
 
@@ -44,6 +48,7 @@ _ALIASES = {
     "rwkv6-1.6b": "rwkv6_1p6b",
     "zamba2-1.2b": "zamba2_1p2b",
     "paligemma-3b": "paligemma_3b",
+    "deepseek-v3": "deepseek_v3",
 }
 
 

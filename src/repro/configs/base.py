@@ -9,14 +9,50 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                 # routed experts the router scores
     top_k: int
     d_ff: int                      # per-expert hidden size
-    capacity_factor: float = 1.25
-    shared_expert: bool = False    # llama4-style always-on shared expert
+    n_shared_experts: int = 0      # always-on shared expert, n x d_ff wide
     dense_residual: bool = False   # arctic-style parallel dense MLP path
-    router_jitter: float = 0.0
+    scoring: str = "softmax"       # softmax | sigmoid (DeepSeek-V3)
+    n_groups: int = 1              # experts in groups; a token keeps
+    topk_group: int = 1            #   the best topk_group groups
+    score_bias: bool = False       # correction bias on the choice (noaux_tc)
+    routed_scaling_factor: float = 1.0     # on the renormalized weights
     aux_loss_weight: float = 0.01
+    # The experts this chip holds, [held_first, held_first + n_held); 0 held
+    # means all of them. The router still scores all n_experts.
+    held_first: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the experts whose weights this layer holds."""
+        return self.held_first, self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): queries through a
+    low-rank latent, keys and values from one cached latent per token plus
+    a shared roped key part."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling (arXiv:2309.00071) in DeepSeek-V3's form."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 1.0    # softmax scale times (0.1 ln factor + 1)^2
 
 
 @dataclass(frozen=True)
@@ -63,6 +99,9 @@ class ModelConfig:
     norm: str = "rmsnorm"          # rmsnorm | layernorm
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
+    first_k_dense: int = 0         # moe: leading layers with a d_ff-wide MLP
+    mla: Optional[MLAConfig] = None    # latent attention instead of GQA
+    rope_scaling: Optional[YaRNConfig] = None
     ssm: Optional[SSMConfig] = None
     attn_every: int = 0            # hybrid: shared attn block after every k SSM layers
     # --- encoder (enc-dec and vlm prefixes) --------------------------------

@@ -15,7 +15,7 @@ CONFIG = ModelConfig(
     d_ff=8192,
     vocab_size=202048,
     rope_theta=500_000.0,
-    moe=MoEConfig(n_experts=128, top_k=1, d_ff=8192, shared_expert=True),
+    moe=MoEConfig(n_experts=128, top_k=1, d_ff=8192, n_shared_experts=1),
     source="hf:meta-llama/Llama-4-Scout-17B-16E; unverified",
 )
 
@@ -23,5 +23,5 @@ SMOKE = CONFIG.replace(
     name="llama4-smoke",
     n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
     vocab_size=512,
-    moe=MoEConfig(n_experts=8, top_k=1, d_ff=128, shared_expert=True),
+    moe=MoEConfig(n_experts=8, top_k=1, d_ff=128, n_shared_experts=1),
 )

@@ -117,7 +117,8 @@ def count_params(cfg: ModelConfig, active_only: bool = False) -> float:
     if active_only and cfg.moe is not None:
         mc = cfg.moe
         per_expert = 3 * cfg.d_model * mc.d_ff
-        inactive = cfg.n_layers * per_expert * (mc.n_experts - mc.top_k)
+        inactive = ((cfg.n_layers - cfg.first_k_dense) * per_expert
+                    * (mc.held[1] - mc.top_k))
         total -= inactive
     return float(total)
 
@@ -197,10 +198,10 @@ def lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh, *, compile_: bool = T
                 batch_shardings(mesh, batch_sds["tokens"]),
                 replicated(mesh),
             )
-            logits_sds = jax.ShapeDtypeStruct(
-                (shape.global_batch, 1, cfg.vocab_size), jnp.float32)
+            logits_sds = jax.ShapeDtypeStruct((shape.global_batch, 1),
+                                              jnp.float32)
             logits_spec = shd.fit_spec(
-                mesh, [_batch_axes(mesh, shape.global_batch), None, "model"],
+                mesh, [_batch_axes(mesh, shape.global_batch), None],
                 logits_sds.shape)
             out_sh = (
                 batch_shardings(mesh, batch_sds["tokens"]),

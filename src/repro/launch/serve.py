@@ -3,9 +3,9 @@ requests through ``repro.serve.ServeScheduler``.
 
 The demo form of the serving stack (docs/serving.md): each request is a
 *generator* work function — each generated token is one yielded
-``(tokens, logits)`` item, so the response's ``first_result_t`` is the
-time-to-first-token and the subsystem's latency accounting applies unchanged
-to token serving. One loaded model answers any number of requests; each
+``(tokens, logits)`` item (the greedy tokens and their logits, ``[B, 1]``
+each), so the response's ``first_result_t`` is the time-to-first-token and
+the subsystem's latency accounting applies unchanged to token serving. One loaded model answers any number of requests; each
 request gets its own cache, which the programs update in place
 (``cache_programs``).
 
@@ -82,9 +82,9 @@ def cache_programs(model, params, batch, cache_len):
 def serve(model, params, prompts, *, gen, cache_len, lanes=1, frames=None):
     """Answer one request per ``[B, P]`` array in ``prompts`` on one loaded
     model. Returns the finished ``Response`` objects; each one's result is
-    ``gen`` items of ``(tokens [B, 1], logits [B, 1, V])``: the prefill's
-    prediction, then one per decode step. ``frames`` (enc-dec only) holds
-    each request's encoder input."""
+    ``gen`` items of ``(tokens [B, 1], logits [B, 1])``, the greedy tokens
+    and their logits: the prefill's prediction, then one per decode step.
+    ``frames`` (enc-dec only) holds each request's encoder input."""
     batch, plen = prompts[0].shape
     frames = frames or [None] * len(prompts)
     new_cache, prefill, serve_step = cache_programs(model, params, batch,

@@ -87,14 +87,20 @@ def make_train_step(model: Model, oc: OptConfig):
     return train_step
 
 
+def _greedy(logits):
+    """logits [B,1,V] -> (the greedy tokens [B,1], their logits [B,1] f32)."""
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return nxt, jnp.take_along_axis(logits, nxt[..., None], -1)[..., 0]
+
+
 def make_serve_step(model: Model):
     """One greedy decode step: (params, cache, tokens[B,1], pos) ->
-    (next_tokens [B,1], logits [B,1,V], cache)."""
+    (next_tokens [B,1], their logits [B,1] f32, cache). A served item does
+    not grow with the vocabulary."""
 
     def serve_step(params, cache, tokens, pos):
         logits, cache = model.decode_step(params, cache, tokens, pos)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return nxt, logits, cache
+        return (*_greedy(logits), cache)
 
     return serve_step
 
@@ -114,10 +120,10 @@ def make_prefill_step(model: Model):
     one-at-a-time loop by ``tests/test_serve.py``.
 
     Returns ``prefill(params, cache, prompts[B, P]) -> (next_tokens[B, 1],
-    logits[B, 1, V], cache)`` — ``serve_step``'s triple, where
+    logits[B, 1], cache)`` — ``serve_step``'s triple, where
     ``next_tokens`` is the greedy prediction after the full prompt (exactly
-    what the first decode step consumes) and ``logits`` are the last prompt
-    position's.
+    what the first decode step consumes) and ``logits`` are its logits at
+    the last prompt position.
     """
 
     def prefill(params, cache, prompts):
@@ -134,8 +140,7 @@ def make_prefill_step(model: Model):
                             jnp.float32)
         (cache, logits), _ = jax.lax.scan(body, (cache, logits0),
                                           (toks, positions))
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return nxt, logits, cache
+        return (*_greedy(logits), cache)
 
     return prefill
 

@@ -98,10 +98,13 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** exponents)  # [Dh/2]
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [..., S, H, Dh]; positions: [..., S] (broadcastable)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: Optional[jax.Array] = None) -> jax.Array:
+    """x: [..., S, H, Dh]; positions: [..., S] (broadcastable). Rotate-half
+    form; ``freqs`` ([Dh/2]) replaces the plain frequencies of ``theta``."""
     dh = x.shape[-1]
-    freqs = rope_freqs(dh, theta)  # [Dh/2]
+    if freqs is None:
+        freqs = rope_freqs(dh, theta)  # [Dh/2]
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # [..., S, Dh/2]
     cos = jnp.cos(angles)[..., None, :]  # [..., S, 1, Dh/2]
     sin = jnp.sin(angles)[..., None, :]

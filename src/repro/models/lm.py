@@ -1,5 +1,10 @@
 """Decoder-only LM assembly: dense / MoE / RWKV-6 / Zamba2-hybrid families.
 
+Attention is GQA, or multi-head latent attention where the config has an
+``mla`` (``models/mla.py``). An MoE model may lead with ``first_k_dense``
+dense layers (their own stack, ``dense_layers``); every layer, dense or
+MoE, shares the one stacked decode cache.
+
 Layers are **scanned** (`lax.scan` over stacked params) so that HLO size and
 compile time are O(1) in depth — required for 126-layer dry-runs — with a
 configurable remat policy. Decode carries the stacked per-layer cache
@@ -19,6 +24,7 @@ from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import layers as L
 from repro.models import mamba2 as m2
+from repro.models import mla
 from repro.models import moe as moe_mod
 from repro.models import rwkv6 as r6
 from repro.sharding import shard_act
@@ -30,22 +36,43 @@ from repro.sharding import shard_act
 # over the stacked cache of every layer.
 # ---------------------------------------------------------------------------
 
+def _leading(cfg: ModelConfig) -> ModelConfig:
+    """The config of an MoE model's leading dense layers."""
+    return cfg.replace(family="dense", moe=None)
+
+
+def _init_attn(cfg: ModelConfig, key):
+    if cfg.mla is not None:
+        return mla.init_mla(cfg, key)
+    return attn.init_attention(cfg, key, cfg.d_model, cfg.n_heads,
+                               cfg.n_kv_heads, cfg.resolved_head_dim)
+
+
+def _attn(cfg: ModelConfig, p, x, prefix_len=None):
+    if cfg.mla is not None:
+        return mla.mla_attention(cfg, p, x)
+    return attn.self_attention(cfg, p, x, causal=True, prefix_len=prefix_len)
+
+
+def _attn_decode(cfg: ModelConfig, p, x, cache, pos, layer):
+    if cfg.mla is not None:
+        return mla.decode_mla(cfg, p, x, cache, pos, layer)
+    return attn.decode_self_attention(cfg, p, x, cache, pos, layer)
+
+
 def init_block(cfg: ModelConfig, key):
-    hd = cfg.resolved_head_dim
     k1, k2, k3, k4 = jax.random.split(key, 4)
     if cfg.family in ("dense", "vlm"):
         return {
             "ln1": L.init_norm(cfg, cfg.d_model),
-            "attn": attn.init_attention(cfg, k1, cfg.d_model, cfg.n_heads,
-                                        cfg.n_kv_heads, hd),
+            "attn": _init_attn(cfg, k1),
             "ln2": L.init_norm(cfg, cfg.d_model),
             "mlp": L.init_mlp(cfg, k2, cfg.d_model, cfg.d_ff),
         }
     if cfg.family == "moe":
         return {
             "ln1": L.init_norm(cfg, cfg.d_model),
-            "attn": attn.init_attention(cfg, k1, cfg.d_model, cfg.n_heads,
-                                        cfg.n_kv_heads, hd),
+            "attn": _init_attn(cfg, k1),
             "ln2": L.init_norm(cfg, cfg.d_model),
             "moe": moe_mod.init_moe(cfg, k2),
         }
@@ -81,12 +108,10 @@ def block_fwd(cfg: ModelConfig, p, x, *, prefix_len=None):
     """Returns (x, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     if cfg.family in ("dense", "vlm"):
-        x = x + attn.self_attention(cfg, p["attn"], L.norm(cfg, p["ln1"], x),
-                                    causal=True, prefix_len=prefix_len)
+        x = x + _attn(cfg, p["attn"], L.norm(cfg, p["ln1"], x), prefix_len)
         x = x + L.mlp(cfg, p["mlp"], L.norm(cfg, p["ln2"], x))
     elif cfg.family == "moe":
-        x = x + attn.self_attention(cfg, p["attn"], L.norm(cfg, p["ln1"], x),
-                                    causal=True)
+        x = x + _attn(cfg, p["attn"], L.norm(cfg, p["ln1"], x))
         y, aux = moe_mod.moe_ffn(cfg, p["moe"], L.norm(cfg, p["ln2"], x))
         x = x + y
     elif cfg.family == "ssm":
@@ -110,6 +135,8 @@ def shared_attn_fwd(cfg: ModelConfig, p, x):
 
 def init_block_cache(cfg: ModelConfig, batch: int, cache_len: int):
     hd = cfg.resolved_head_dim
+    if cfg.mla is not None:         # the latent c_kv and the roped k_pe
+        return {"cache": mla.init_cache(cfg, batch, cache_len)}
     if cfg.family in ("dense", "vlm", "moe"):
         return {"cache": attn.init_decode_cache(cfg, batch, cache_len,
                                                 cfg.n_kv_heads, hd)}
@@ -138,12 +165,12 @@ def block_decode(cfg: ModelConfig, p, x, cache, layer, pos):
     layer's new entries in place. Returns (x, cache)."""
     c = cache["cache"]
     if cfg.family in ("dense", "vlm", "moe"):
-        y, c = attn.decode_self_attention(cfg, p["attn"],
-                                          L.norm(cfg, p["ln1"], x), c, pos,
-                                          layer)
+        y, c = _attn_decode(cfg, p["attn"], L.norm(cfg, p["ln1"], x), c,
+                            pos, layer)
         x = x + y
         if cfg.family == "moe":
-            y, _ = moe_mod.moe_ffn(cfg, p["moe"], L.norm(cfg, p["ln2"], x))
+            y, _ = moe_mod.moe_ffn(cfg, p["moe"], L.norm(cfg, p["ln2"], x),
+                                   layer - cfg.first_k_dense)
         else:
             y = L.mlp(cfg, p["mlp"], L.norm(cfg, p["ln2"], x))
         x = x + y
@@ -225,9 +252,12 @@ def init_lm(cfg: ModelConfig, key):
     ke, kl, kh, ks = jax.random.split(key, 4)
     params: dict[str, Any] = {
         "embed": L.init_embed(cfg, ke, cfg.vocab_size, cfg.d_model),
-        "layers": _stacked_init(cfg, kl, cfg.n_layers),
+        "layers": _stacked_init(cfg, kl, cfg.n_layers - cfg.first_k_dense),
         "final_norm": L.init_norm(cfg, cfg.d_model),
     }
+    if cfg.first_k_dense:
+        params["dense_layers"] = _stacked_init(
+            _leading(cfg), jax.random.fold_in(kl, 1), cfg.first_k_dense)
     if not cfg.tie_embeddings:
         params["lm_head"] = L.init_unembed(cfg, kh, cfg.d_model, cfg.vocab_size)
     if cfg.family == "hybrid" and cfg.attn_every:
@@ -264,10 +294,11 @@ def _scan_blocks(cfg: ModelConfig, layers_p, x, *, prefix_len=None):
 
 
 def _hybrid_groups(cfg: ModelConfig):
-    k = cfg.attn_every
-    full = cfg.n_layers // k if k else 0
-    tail = cfg.n_layers - full * k if k else cfg.n_layers
-    return full, tail
+    """(groups of ``attn_every`` layers, layers after them) of the main
+    stack (the layers after any leading dense ones)."""
+    k, n = cfg.attn_every, cfg.n_layers - cfg.first_k_dense
+    full = n // k if k else 0
+    return full, n - full * k
 
 
 def _hybrid_fwd(cfg: ModelConfig, params, x):
@@ -320,6 +351,8 @@ def lm_forward(cfg: ModelConfig, params, tokens: jax.Array,
     if cfg.family == "hybrid":
         x, aux = _hybrid_fwd(cfg, params, x)
     else:
+        if cfg.first_k_dense:
+            x, _ = _scan_blocks(_leading(cfg), params["dense_layers"], x)
         x, aux = _scan_blocks(cfg, params["layers"], x, prefix_len=prefix_len)
 
     x = L.norm(cfg, params["final_norm"], x)
@@ -375,12 +408,21 @@ def _keep_layout(cache, layout):
 def _decode_layers(cfg: ModelConfig, layers_p, x, cache, first, pos, layout):
     """Decode through stacked layers ``layers_p`` (``[n, ...]``), layer
     ``first`` onwards, carrying the whole stacked cache through the loop so
-    each layer writes its entries into it in place. Returns (x, cache)."""
+    each layer writes its entries into it in place. The experts' weights
+    stay whole, outside the loop's slices: each layer's grouped product
+    reads its own groups of the stack in place. Returns (x, cache)."""
     n = jax.tree.leaves(layers_p)[0].shape[0]
+    whole = {}
+    if "moe" in layers_p:
+        moe = dict(layers_p["moe"])
+        whole = {k: moe.pop(k) for k in moe_mod.EXPERTS}
+        layers_p = dict(layers_p, moe=moe)
 
     def body(carry, inp):
         x, cache = carry
         lp, layer = inp
+        if whole:
+            lp = dict(lp, moe=dict(lp["moe"], **whole))
         x, cache = block_decode(cfg, lp, x, cache, layer, pos)
         return (x, _keep_layout(cache, layout)), None
 
@@ -393,9 +435,10 @@ def lm_decode_step(cfg: ModelConfig, params, cache: dict, tokens: jax.Array,
                    pos: jax.Array, cache_layout=None):
     """One decode step. tokens: [B,1]; pos: [] -> (logits [B,1,V], cache).
 
-    Every family runs one loop: groups of ``attn_every`` layers, each
-    followed by the shared attention block (zamba2), then the remaining
-    layers (all of them where no block is shared). The cache goes through
+    Every family runs one loop: any leading dense layers, then groups of
+    ``attn_every`` layers, each followed by the shared attention block
+    (zamba2), then the remaining layers (all of them where no block is
+    shared). The cache goes through
     the loops as their carry, never as scanned inputs and outputs, and
     each layer writes only its new entries.
 
@@ -413,6 +456,9 @@ def lm_decode_step(cfg: ModelConfig, params, cache: dict, tokens: jax.Array,
     k = cfg.attn_every
     layers_p, lc = params["layers"], cache["layers"]
     sac = cache.get("shared_attn")
+    if cfg.first_k_dense:
+        x, lc = _decode_layers(_leading(cfg), params["dense_layers"], x, lc,
+                               0, pos, layout.get("layers"))
     if full:
         gp = jax.tree.map(lambda a: a[: full * k].reshape(full, k, *a.shape[1:]),
                           layers_p)
@@ -430,7 +476,8 @@ def lm_decode_step(cfg: ModelConfig, params, cache: dict, tokens: jax.Array,
                                      (gp, jnp.arange(full)))
         layers_p = jax.tree.map(lambda a: a[full * k:], layers_p)
     if tail:
-        x, lc = _decode_layers(cfg, layers_p, x, lc, full * k, pos,
+        x, lc = _decode_layers(cfg, layers_p, x, lc,
+                               cfg.first_k_dense + full * k, pos,
                                layout.get("layers"))
     new_cache = {"layers": lc}
     if sac is not None:

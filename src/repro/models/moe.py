@@ -1,31 +1,42 @@
-"""Top-k routed mixture-of-experts with capacity-based dispatch.
+"""Top-k routed mixture-of-experts that drops no token, over the experts
+this chip holds.
 
-Implementation notes (these choices matter for the roofline):
+  * **Routing over every expert.** The router scores all ``n_experts`` in
+    float32 (softmax, or DeepSeek-V3's sigmoid with a correction bias that
+    moves the choice but not the weights), optionally keeps the best
+    ``topk_group`` of ``n_groups`` expert groups, and picks the top k.
+  * **Held experts.** A layer holds the weights of experts
+    ``[held_first, held_first + n_held)`` only (all of them by default), as
+    one chip of an expert-parallel deployment does, and computes their part
+    of each token's output; picks of other experts are another chip's work.
+  * **No capacity, no drop.** The (token, pick) pairs are sorted by held
+    expert and multiplied by one grouped product over the held experts
+    (``jax.lax.ragged_dot``); picks of experts held elsewhere sort past the
+    last group and contribute nothing. Every token's output depends on its
+    own row alone. The product's row tile (``ragged_dot_tiling``, read by
+    the TPU compiler) is about the rows an expert expects, so an expert
+    with a few rows does not fill a 512-row tile in vain.
+  * A shared expert (``n_shared_experts`` x ``d_ff`` wide) or a dense
+    residual MLP (arctic) is added to every token.
 
-  * **No dense GShard dispatch einsum.** The classic `[G,T,E,C]` one-hot
-    einsum costs `T*E*C*D` MAC FLOPs — orders of magnitude more than the
-    expert FFNs themselves at 128 experts. We instead build an `[B,E,C]`
-    integer routing table (masked-cumsum positions, scatter once) and use
-    *gathers* both to dispatch and to combine, so compiled FLOPs stay at the
-    true `topk * cf * T * D * F` scale.
-  * Routing is per-group where a group is one batch row (tokens stay on
-    their data shard; only the `[B,E,C,D]` expert buffers reshard across the
-    `model` axis, which is the all-to-all the paper-style two-lane schedule
-    overlaps in §Perf).
-  * Experts are stacked `[E, D, F]` and sharded E→model (8 experts/device at
-    E=128, TP=16).
+Named scopes: ``moe.router``, ``moe.experts``, ``moe.shared``.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import math
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.models.layers import _act, _normal, dt, init_mlp, mlp
 from repro.sharding import shard_act
+
+HIGHEST = jax.lax.Precision.HIGHEST
+EXPERTS = ("w_gate", "w_up", "w_down")     # the held experts' weights
 
 
 def init_moe(cfg: ModelConfig, key):
@@ -33,107 +44,115 @@ def init_moe(cfg: ModelConfig, key):
     assert mc is not None
     kr, kg, ku, kd, ks = jax.random.split(key, 5)
     pd = dt(cfg.param_dtype)
-    d, f, e = cfg.d_model, mc.d_ff, mc.n_experts
+    d, f, e = cfg.d_model, mc.d_ff, mc.held[1]
     p = {
-        "router": _normal(kr, (d, e), d ** -0.5, pd),
+        "router": _normal(kr, (d, mc.n_experts), d ** -0.5, pd),
         "w_gate": _normal(kg, (e, d, f), d ** -0.5, pd),
         "w_up": _normal(ku, (e, d, f), d ** -0.5, pd),
         "w_down": _normal(kd, (e, f, d), f ** -0.5, pd),
     }
-    if mc.shared_expert or mc.dense_residual:
-        p["shared"] = init_mlp(cfg, ks, d, f if mc.shared_expert else cfg.d_ff)
+    if mc.score_bias:
+        p["score_bias"] = jnp.zeros((mc.n_experts,), pd)
+    if mc.n_shared_experts or mc.dense_residual:
+        p["shared"] = init_mlp(cfg, ks, d, mc.n_shared_experts * f
+                               if mc.n_shared_experts else cfg.d_ff)
     return p
 
 
-def _capacity(mc: MoEConfig, tokens_per_group: int) -> int:
-    c = int(mc.top_k * tokens_per_group * mc.capacity_factor / mc.n_experts)
-    return max(c, 4)
+def route(mc: MoEConfig, logits: jax.Array, bias=None):
+    """logits: [N, E] float32 -> (expert_idx [N,K], weights [N,K], aux).
+
+    ``bias`` (``[E]``) is added to the scores for the choice only; the
+    weights are the chosen experts' own scores, renormalized to sum 1 and
+    scaled by ``routed_scaling_factor``."""
+    n, e = logits.shape
+    if mc.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    if mc.n_groups > 1:
+        grouped = choice.reshape(n, mc.n_groups, e // mc.n_groups)
+        group_score = (grouped.max(-1) if bias is None
+                       else jax.lax.top_k(grouped, 2)[0].sum(-1))
+        _, keep = jax.lax.top_k(group_score, mc.topk_group)        # [N, kg]
+        kept = jnp.zeros((n, mc.n_groups), bool).at[
+            jnp.arange(n)[:, None], keep].set(True)
+        choice = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(n, e)
+    _, expert_idx = jax.lax.top_k(choice, mc.top_k)                 # [N, K]
+    weights = jnp.take_along_axis(scores, expert_idx, axis=-1)
+    weights = weights / (weights.sum(-1, keepdims=True) + 1e-20) \
+        * mc.routed_scaling_factor
+
+    # Load-balance aux loss (Switch-style) over all experts.
+    probs = scores / scores.sum(-1, keepdims=True)
+    picked = jax.nn.one_hot(expert_idx, e, dtype=jnp.float32).sum(1)  # [N, E]
+    aux = e * jnp.sum(probs.mean(0) * picked.mean(0) / mc.top_k)
+    return expert_idx, weights, aux
 
 
-def route(mc: MoEConfig, logits: jax.Array, capacity: int):
-    """logits: [B,S,E] -> routing tables.
-
-    Returns (expert_idx [B,S,K], probs [B,S,K], slot [B,S,K], keep [B,S,K],
-    aux_loss scalar).
-    """
-    b, s, e = logits.shape
-    gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    probs, expert_idx = jax.lax.top_k(gates, mc.top_k)          # [B,S,K]
-
-    # Position of each (token, choice) inside its expert's buffer: masked
-    # cumulative count over the sequence, counting earlier top-k slots first.
-    onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.int32)      # [B,S,K,E]
-    # counts of the same expert in earlier slots of the same token
-    prior_slots = jnp.cumsum(onehot, axis=2) - onehot            # [B,S,K,E]
-    # counts from earlier tokens (all slots)
-    prior_tokens = jnp.cumsum(onehot.sum(2), axis=1) - onehot.sum(2)  # [B,S,E]
-    pos = prior_tokens[:, :, None, :] + prior_slots              # [B,S,K,E]
-    slot = (pos * onehot).sum(-1)                                # [B,S,K]
-    keep = slot < capacity
-
-    # Load-balance aux loss (Switch-style).
-    me = gates.mean(axis=(0, 1))                                 # [E]
-    ce = onehot.sum(2).astype(jnp.float32).mean(axis=(0, 1)) / mc.top_k
-    aux = e * jnp.sum(me * ce)
-
-    return expert_idx, probs, slot, keep, aux
+def row_tile(rows: int, mc: MoEConfig) -> int:
+    """Rows of the grouped product's tiles: the rows an expert expects
+    (``rows * top_k / n_experts``) to a power of two, from the MXU's 128 up
+    to the compiler's default 512."""
+    expected = rows * mc.top_k / mc.n_experts
+    return int(min(512, max(128, 2 ** math.ceil(math.log2(max(expected, 1))))))
 
 
-def moe_ffn(cfg: ModelConfig, p, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """x: [B,S,D] -> (y [B,S,D], aux_loss)."""
+def held_experts(cfg: ModelConfig, p, x: jax.Array, expert_idx: jax.Array,
+                 weights: jax.Array, layer=None) -> jax.Array:
+    """The held experts' part of each token's output: x [N, D], picks
+    ``expert_idx``/``weights`` [N, K] -> [N, D] float32.
+
+    ``p``'s expert weights are one layer's (``[e, ...]``) or, with
+    ``layer``, every layer's (``[L, e, ...]``): this layer's picks then go
+    to its own groups of the whole stack, which the grouped product reads
+    in place (a slice of the stack would be a copy of the layer's
+    weights)."""
+    mc, cd = cfg.moe, dt(cfg.compute_dtype)
+    n, k = expert_idx.shape
+    first, count = mc.held
+    ws = [p[name] for name in EXPERTS]
+    offset = 0
+    if ws[0].ndim == 4:
+        offset = layer * count
+        ws = [w.reshape(-1, *w.shape[2:]) for w in ws]
+    groups = ws[0].shape[0]
+    local = expert_idx.reshape(n * k) - first
+    held = (local >= 0) & (local < count)
+    group = jnp.where(held, local + offset, groups)  # other chips' picks last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=groups + 1)[:groups].astype(jnp.int32)
+    rows = x.astype(cd)[order // k]                                 # [N*K, D]
+    with set_xla_metadata(ragged_dot_tiling=f"{row_tile(n, mc)},512,512"):
+        gate = jax.lax.ragged_dot(rows, ws[0].astype(cd), sizes)
+        up = jax.lax.ragged_dot(rows, ws[1].astype(cd), sizes)
+        h = (_act(cfg.act, gate) * up).astype(cd)
+        out = jax.lax.ragged_dot(h, ws[2].astype(cd), sizes,
+                                 preferred_element_type=jnp.float32)
+    # Back to (token, pick) order; rows past the held groups are zero.
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+    out = out[back].reshape(n, k, -1)
+    w = jnp.where(held, weights.reshape(n * k), 0.0).reshape(n, k, 1)
+    return (out * w).sum(1)
+
+
+def moe_ffn(cfg: ModelConfig, p, x: jax.Array,
+            layer=None) -> Tuple[jax.Array, jax.Array]:
+    """x: [B,S,D] -> (y [B,S,D], aux_loss). ``layer``: see
+    ``held_experts``."""
     mc = cfg.moe
-    cd = dt(cfg.compute_dtype)
     b, s, d = x.shape
-    e = mc.n_experts
-    cap = _capacity(mc, s)
-
-    logits = jnp.einsum("bsd,de->bse", x.astype(cd), p["router"].astype(cd))
-    expert_idx, probs, slot, keep, aux = route(mc, logits, cap)
-
-    # ----- dispatch: build [B,E,C] token-index table, then gather ----------
-    # flatten the K choices; dropped (overflow) entries scatter out of range.
-    flat_e = expert_idx.reshape(b, s * mc.top_k)
-    flat_slot = jnp.where(keep, slot, cap).reshape(b, s * mc.top_k)
-    token_of_choice = jnp.broadcast_to(
-        jnp.arange(s)[:, None], (s, mc.top_k)
-    ).reshape(s * mc.top_k)
-
-    def build_table(e_row, slot_row):
-        tbl = jnp.zeros((e, cap + 1), jnp.int32)
-        tbl = tbl.at[e_row, slot_row].set(token_of_choice, mode="drop")
-        return tbl[:, :cap]
-
-    idx_table = jax.vmap(build_table)(flat_e, flat_slot)         # [B,E,C]
-
-    x_e = jnp.take_along_axis(
-        x[:, :, None, :], idx_table.reshape(b, e * cap)[..., None, None], axis=1
-    )
-    x_e = x_e.reshape(b, e, cap, d)
-    x_e = shard_act(x_e, "batch", "model", None, None)
-
-    # ----- expert FFNs (batched over E) -------------------------------------
-    xc = x_e.astype(cd)
-    up = jnp.einsum("becd,edf->becf", xc, p["w_up"].astype(cd))
-    gate = _act(cfg.act, jnp.einsum("becd,edf->becf", xc, p["w_gate"].astype(cd)))
-    h = gate * up
-    y_e = jnp.einsum("becf,efd->becd", h, p["w_down"].astype(cd))
-    y_e = shard_act(y_e, "batch", "model", None, None)
-
-    # ----- combine: K gathers back to token order ---------------------------
-    y = jnp.zeros((b, s, d), jnp.float32)
-    flat_ec = (expert_idx * cap + jnp.minimum(slot, cap - 1))    # [B,S,K]
-    y_flat = y_e.reshape(b, e * cap, d)
-    for j in range(mc.top_k):
-        gj = jnp.take_along_axis(y_flat, flat_ec[:, :, j][..., None], axis=1)
-        wj = (probs[:, :, j] * keep[:, :, j]).astype(jnp.float32)
-        y = y + wj[..., None] * gj.astype(jnp.float32)
-
-    # normalize combined top-k weights (llama4/arctic convention)
-    denom = (probs * keep).sum(-1, keepdims=True)
-    y = y / jnp.maximum(denom, 1e-9)
-
-    y = y.astype(x.dtype)
+    flat = x.reshape(b * s, d)
+    with jax.named_scope("moe.router"):
+        logits = jnp.dot(flat.astype(jnp.float32),
+                         p["router"].astype(jnp.float32), precision=HIGHEST)
+        expert_idx, weights, aux = route(mc, logits, p.get("score_bias"))
+    with jax.named_scope("moe.experts"):
+        y = held_experts(cfg, p, flat, expert_idx, weights, layer)
+        y = y.astype(x.dtype).reshape(b, s, d)
     if "shared" in p:
-        y = y + mlp(cfg, p["shared"], x)
+        with jax.named_scope("moe.shared"):
+            y = y + mlp(cfg, p["shared"], x)
     y = shard_act(y, "batch", None, "model", kind="resid")
     return y, aux * mc.aux_loss_weight

@@ -187,6 +187,52 @@ def test_phi3_serve_keeps_the_cache_in_place(one_chip, step):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
+def _dsv3_serve_programs(one_chip, step, batch, cache_len, prompt_len):
+    """The benchmark cell ``dsv3-ep32-serve-decode``'s prefill or decode
+    step (DeepSeek-V3 at published widths, 1 dense and 4 MoE layers, 8
+    held experts), compiled as ``serve()`` compiles them."""
+    from bench.run import load_cell, load_spec
+    from bench.weights_mla_moe import model_config
+
+    c = load_cell(load_spec(), "dsv3-ep32-serve-decode")["config_file"]
+    model = build_model(model_config(c, param_dtype=c["param_dtype"],
+                                     compute_dtype=c["compute_dtype"]))
+    params = _placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                     one_chip)
+    _, prefill, serve_step = cache_programs(model, params, batch, cache_len)
+    cache = _placed(jax.eval_shape(lambda: model.init_cache(batch, cache_len)),
+                    one_chip)
+    if step == "serve_step":
+        fn, args = serve_step, (_sds((batch, 1), jnp.int32, one_chip),
+                                _sds((), jnp.int32, one_chip))
+    else:
+        fn, args = prefill, (_sds((batch, prompt_len), jnp.int32, one_chip),)
+    return fn.lower(params, cache, *args).compile(), c
+
+
+@pytest.mark.parametrize("step", ["prefill", "serve_step"])
+def test_dsv3_serve_fits_and_keeps_the_latent_cache_in_place(one_chip, step):
+    """At the cell's sizes (batch 256, cache 512, prompt 64) the programs
+    serve() builds fit one chip's HBM and copy no part of the latent cache
+    of a layer's size or more: no copy has the shape of one layer's
+    ``c_kv`` or ``k_pe`` slab, or of their stacks, in any order of dims."""
+    batch, cache_len, prompt_len = 256, 512, 64
+    compiled, c = _dsv3_serve_programs(one_chip, step, batch, cache_len,
+                                       prompt_len)
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BUDGET, (
+        ma.argument_size_in_bytes / GiB, ma.temp_size_in_bytes / GiB)
+    slabs = {tuple(sorted(d for d in (n, batch, cache_len, w) if d > 1))
+             for n in (1, c["num_hidden_layers"])
+             for w in (c["kv_lora_rank"], c["qk_rope_head_dim"])}
+    slab = batch * cache_len * c["qk_rope_head_dim"] * 2
+    copied = [(name, shape) for name, shape in
+              _copies_of_at_least(compiled.as_text(), slab)
+              if tuple(sorted(int(d) for d in shape.split("[")[1][:-1]
+                              .split(",") if int(d) > 1)) in slabs]
+    assert copied == []
+
+
 def test_relic_tiny_train_step_fits_one_chip(one_chip):
     model = build_model(get_config("relic_tiny"))
     state = _placed(jax.eval_shape(
