@@ -37,6 +37,7 @@ from repro import sharding as shd
 from repro.configs import ARCH_IDS, SHAPES, get_config, shape_applicable
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.launch.mesh import make_mesh, make_production_mesh
+from repro.launch.peaks import PEAKS
 from repro.launch.steps import (
     abstract_serve_state,
     abstract_train_state,
@@ -48,9 +49,9 @@ from repro.optim import OptConfig
 
 ART_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "artifacts" / "dryrun"
 
-# v5e-flavoured hardware constants (per chip)
-PEAK_FLOPS = 197e12        # bf16
-HBM_BW = 819e9             # B/s
+# v5e hardware constants (per chip)
+PEAK_FLOPS = PEAKS["TPU v5 lite"]["bf16_flops_per_s"]
+HBM_BW = PEAKS["TPU v5 lite"]["hbm_bytes_per_s"]
 ICI_BW = 50e9              # B/s per link
 
 _COLL_RE = re.compile(

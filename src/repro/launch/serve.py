@@ -5,9 +5,10 @@ The demo form of the serving stack (docs/serving.md): each request is a
 *generator* work function — each generated token is one yielded
 ``(tokens, logits)`` item (the greedy tokens and their logits, ``[B, 1]``
 each), so the response's ``first_result_t`` is the time-to-first-token and
-the subsystem's latency accounting applies unchanged to token serving. One loaded model answers any number of requests; each
-request gets its own cache, which the programs update in place
-(``cache_programs``).
+the subsystem's latency accounting applies unchanged to token serving. One loaded model answers any number of requests; queued
+requests of equal shapes are served together as one batch, up to the
+chip's ridge row count (``serve``), and each group gets its own cache,
+which the programs update in place (``cache_programs``).
 
 Prefill is ``make_prefill_step`` — one jitted ``lax.scan`` dispatch over
 the prompt positions instead of O(prompt_len) ``serve_step`` dispatches
@@ -30,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.peaks import ridge_rows
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.models import build_model
 from repro.runtime.metrics import span
@@ -79,18 +81,130 @@ def cache_programs(model, params, batch, cache_len):
             jax.jit(make_serve_step(held), donate_argnums=(1,)))
 
 
-def serve(model, params, prompts, *, gen, cache_len, lanes=1, frames=None):
-    """Answer one request per ``[B, P]`` array in ``prompts`` on one loaded
-    model. Returns the finished ``Response`` objects; each one's result is
-    ``gen`` items of ``(tokens [B, 1], logits [B, 1])``, the greedy tokens
-    and their logits: the prefill's prediction, then one per decode step.
-    ``frames`` (enc-dec only) holds each request's encoder input."""
-    batch, plen = prompts[0].shape
-    frames = frames or [None] * len(prompts)
-    new_cache, prefill, serve_step = cache_programs(model, params, batch,
-                                                    cache_len)
+def plan_groups(keys, rows, max_rows, fits):
+    """Split a queue into groups served as one batch, in queue order. A
+    request joins the open group when its ``keys`` entry (its prompt's
+    shape, and its frames') equals the group's, the group's ``rows`` stay
+    within ``max_rows`` and ``fits(rows)`` holds for the merged rows;
+    otherwise it opens the next group. ``max_rows=None`` merges nothing.
+    Returns the groups as lists of queue indices."""
+    groups = []
+    for i, (key, r) in enumerate(zip(keys, rows)):
+        if groups and max_rows is not None and keys[groups[-1][0]] == key:
+            merged = r + sum(rows[j] for j in groups[-1])
+            if merged <= max_rows and fits(merged):
+                groups[-1].append(i)
+                continue
+        groups.append([i])
+    return groups
 
-    def generate(rid, prompt, req_frames):
+
+def group_programs(model, params, n, prompt, frames, cache_len):
+    """The programs one group of ``n`` requests runs, compiled ahead of
+    time for its shapes: ``prompt`` and ``frames`` are one member's (arrays
+    or ``ShapeDtypeStruct``s; ``frames`` None but for enc-dec). Returns
+    ``(new_cache, prefill, step, join, split)``: ``cache_programs`` at the
+    group's rows, lowered on the same abstract arguments as the benchmark's
+    scope map; ``join`` concatenates the members' ``(prompt, frames)``
+    along the batch and ``split`` cuts a step's ``(tokens, logits)`` back
+    into the members' rows (both None for a group of one)."""
+    sharding = jax.tree.leaves(params)[0].sharding
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    rows = n * prompt.shape[0]
+    cache = jax.tree.map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+        lambda: model.init_cache(rows, cache_len)))
+    member = (sds(prompt.shape, prompt.dtype),
+              None if frames is None else sds(frames.shape, frames.dtype))
+    whole = jax.tree.map(lambda x: sds((rows, *x.shape[1:]), x.dtype),
+                         member)
+    new_cache, prefill, step = cache_programs(model, params, rows, cache_len)
+    step = step.lower(params, cache, sds((rows, 1), jnp.int32),
+                      sds((), jnp.int32)).compile()
+    programs = (new_cache.lower(params, whole[1]).compile(),
+                prefill.lower(params, cache, whole[0]).compile(), step)
+    if n == 1:
+        return (*programs, None, None)
+    join = jax.jit(lambda *ms: jax.tree.map(
+        lambda *xs: jnp.concatenate(xs), *ms)).lower(*[member] * n)
+    split = jax.jit(lambda tok, logits: list(zip(
+        jnp.split(tok, n), jnp.split(logits, n)))).lower(*step.out_info[:2])
+    return (*programs, join.compile(), split.compile())
+
+
+def _max_rows(params):
+    """The chip's ridge row count for these weights (``peaks.ridge_rows``),
+    None on a chip not in the table."""
+    leaves = jax.tree.leaves(params)
+    device = next(iter(leaves[0].sharding.device_set))
+    bytes_per_weight = (sum(x.size * x.dtype.itemsize for x in leaves)
+                        / sum(x.size for x in leaves))
+    return ridge_rows(device.device_kind, bytes_per_weight)
+
+
+def _cache_fits(model, params, cache_len, lanes):
+    """``fits(rows)``: whether a cache of that many rows fits the device's
+    free memory once for each lane that may hold one; always where the
+    device reports no memory."""
+    device = next(iter(jax.tree.leaves(params)[0].sharding.device_set))
+    stats = device.memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return lambda rows: True
+    free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+
+    def fits(rows):
+        cache = jax.eval_shape(lambda: model.init_cache(rows, cache_len))
+        return max(lanes, 1) * sum(x.size * x.dtype.itemsize for x in
+                                   jax.tree.leaves(cache)) <= free
+
+    return fits
+
+
+def serve(model, params, prompts, *, gen, cache_len, lanes=1, frames=None,
+          max_rows=None):
+    """Answer one request per ``[B, P]`` array in ``prompts`` on one loaded
+    model. Returns the finished ``Response`` objects, one per prompt, in
+    order; each one's result is ``gen`` items of ``(tokens [B, 1], logits
+    [B, 1])``, the greedy tokens and their logits: the prefill's
+    prediction, then one per decode step. ``frames`` (enc-dec only) holds
+    each request's encoder input.
+
+    Queued requests of equal shapes are served as one batch
+    (``plan_groups``): one cache, one prefill and one decode loop over the
+    members' rows, up to ``max_rows`` rows, by default the chip's ridge
+    row count (``peaks.ridge_rows``; nothing merges on a chip not in the
+    table), and only where the merged cache fits the device's free
+    memory. The programs of each group shape are compiled before the
+    queue is read; ``prompts`` is read by ``len()`` and indexing until
+    then, and iterated once, when the groups are submitted."""
+    n = len(prompts)
+    frames = [None] * n if frames is None else frames
+    shapes = [(prompts[i].shape, prompts[i].dtype) for i in range(n)]
+    frame_shapes = [None if f is None else (f.shape, f.dtype) for f in frames]
+    if max_rows is None:
+        max_rows = _max_rows(params)
+    groups = plan_groups(list(zip(shapes, frame_shapes)),
+                         [shape[0] for shape, _ in shapes], max_rows,
+                         _cache_fits(model, params, cache_len, lanes))
+    programs, plans = {}, []
+    for g in groups:
+        key = (len(g), shapes[g[0]], frame_shapes[g[0]])
+        if key not in programs:
+            programs[key] = group_programs(model, params, len(g),
+                                           prompts[g[0]], frames[g[0]],
+                                           cache_len)
+        plans.append((g, programs[key]))
+
+    def generate(rid, members, progs):
+        new_cache, prefill, serve_step, join, split = progs
+        prompt, req_frames = members[0] if join is None else join(*members)
+        plen = prompt.shape[1]
+
+        def parts(tok, logits):
+            return [(tok, logits)] if split is None else split(tok, logits)
+
         with span("serve.cache_init", rid=rid):
             cache = new_cache(params, req_frames)
         with span("serve.prefill", rid=rid):
@@ -99,29 +213,24 @@ def serve(model, params, prompts, *, gen, cache_len, lanes=1, frames=None):
         # so first_result_t (TTFT) covers the prefill, not its dispatch.
         with span("serve.first_token", rid=rid):
             jax.block_until_ready(tok)
-        yield tok, logits
+        yield parts(tok, logits)
         for t in range(plen, plen + gen - 1):
             with span("serve.step", rid=rid, pos=t):
                 tok, logits, cache = serve_step(params, cache, tok,
                                                 jnp.int32(t))
-            yield tok, logits
+            yield parts(tok, logits)
         with span("serve.finish", rid=rid):
             jax.block_until_ready(tok)
 
-    # Warm every program off the served path on one throwaway cache (the
-    # prefill and the step donate it), so served requests measure
-    # steady-state steps, not compilation.
-    tok, _, cache = prefill(params, new_cache(params, frames[0]),
-                            jnp.zeros_like(prompts[0]))
-    jax.block_until_ready(serve_step(params, cache, tok, jnp.int32(plen))[0])
-    del cache
-
     with ServeScheduler(lanes=lanes) as server:
         client = server.open_client("decode")
+        queue = iter(prompts)       # the one pass over the queue
         resps = []
-        for p, f in zip(prompts, frames):
+        for g, progs in plans:
+            members = [(next(queue), frames[i]) for i in g]
             rid = Request.next_rid()
-            resps.append(client.submit(generate, rid, p, f, rid=rid))
+            resps += client.submit_group(generate, len(g), rid, members,
+                                         progs, rid=rid)
         for resp in resps:
             assert len(resp.result()) == gen, (len(resp.result()), gen)
     return resps

@@ -29,6 +29,7 @@ paths never do.
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -118,6 +119,42 @@ class ClientHandle:
         caller that passes its own draws it from there too, so that its
         work function can carry the id the server's spans carry.
         """
+        return self._push(self._request(fn, args, deadline_s, idempotent,
+                                        rid), must_admit)
+
+    def submit_group(
+        self,
+        fn: Callable[..., Any],
+        n: int,
+        *args: Any,
+        deadline_s: Optional[float] = None,
+        must_admit: bool = False,
+        rid: Optional[int] = None,
+    ) -> Optional[List[Response]]:
+        """Enqueue ``n`` requests that one call of ``fn(*args)`` serves on
+        one lane; returns their ``n`` ``Response`` futures, in order.
+
+        ``fn`` returns a sequence of ``n`` values, or is a generator whose
+        every item is a sequence of ``n`` parts: member ``i``'s result is
+        the ``i``-th value, or the list of the ``i``-th parts. The group
+        takes one ring slot and is admitted whole; every member gets the
+        group's ``admit_t``, its first item's time as ``first_result_t``
+        and the group's ``complete_t``. A failure fails every member, and
+        a group is never retried. Admission, ``deadline_s`` and
+        ``must_admit`` are ``submit``'s; ``rid`` is the first member's id.
+        """
+        if n < 1:
+            raise ValueError(f"a group has at least one request, got {n}")
+        lead = self._request(fn, args, deadline_s, False, rid)
+        lead.request.group = (lead, *(
+            Response(dataclasses.replace(lead.request,
+                                         rid=Request.next_rid()))
+            for _ in range(n - 1)))
+        if self._push(lead, must_admit) is None:
+            return None
+        return list(lead.request.group)
+
+    def _request(self, fn, args, deadline_s, idempotent, rid) -> Response:
         self._check_producer()
         if self._closed:
             raise ServeUsageError(
@@ -125,7 +162,7 @@ class ClientHandle:
         arrival = now()
         if deadline_s is None:
             deadline_s = self._default_deadline_s
-        req = Request(
+        return Response(Request(
             rid=Request.next_rid() if rid is None else rid,
             client_id=self.client_id,
             fn=fn,
@@ -133,8 +170,9 @@ class ClientHandle:
             arrival_t=arrival,
             deadline_t=None if deadline_s is None else arrival + deadline_s,
             idempotent=idempotent,
-        )
-        resp = Response(req)
+        ))
+
+    def _push(self, resp: Response, must_admit: bool) -> Optional[Response]:
         ring = self._ring
         if not ring.push(resp):
             if self._admission == "reject":

@@ -16,6 +16,10 @@ Timestamps are ``time.perf_counter()`` seconds (see ``metrics.now``):
 - ``first_result_t`` — first streamed item for generator work (TTFT for the
   token-serving demo); equals completion for scalar work
 - ``complete_t``  — stamped when the work function returns/raises
+
+The members of a group (``ClientHandle.submit_group``: one call of the work
+function serves several requests) share the group's ``admit_t``,
+``first_result_t`` and ``complete_t``.
 """
 
 from __future__ import annotations
@@ -52,6 +56,10 @@ class Request:
     deadline_t: Optional[float] = None   # absolute perf_counter deadline
     admit_t: Optional[float] = None      # stamped by the scheduler
     idempotent: bool = False             # safe to re-run on failure
+    # On the first member of a group only: every member's Response, this
+    # one first. The group's one work call yields (or returns) one part
+    # per member, in this order.
+    group: Optional[Tuple["Response", ...]] = None
 
     @staticmethod
     def next_rid() -> int:

@@ -13,7 +13,9 @@ cycle:
    requests whose deadline already passed (surfaced as
    ``deadline_exceeded``, never silently dropped), and submit the rest to
    the pool lanes via ``submit_many`` (lane striping + rebalance are the
-   existing RelicPool machinery);
+   existing RelicPool machinery). A group (``ClientHandle.submit_group``)
+   is one ring entry and one lane task: it takes one entry of the budget
+   and is admitted, shed or cancelled whole, all its members in flight;
 3. **park** — when idle long enough, publish a parked flag and sleep on an
    Event that ``ClientHandle.submit`` sets only when it observes the flag —
    the same advisory-hint philosophy as ``Relic.sleep_hint`` /
@@ -49,6 +51,7 @@ the PR 7 cycle.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -81,6 +84,12 @@ _PARK_AFTER_IDLE_SPINS = 256
 # Park timeout: an advisory-hint backstop, not the wake mechanism (the
 # Event is); bounds stop() latency if every hint is missed.
 _PARK_TIMEOUT_S = 0.05
+
+
+def _members(resp: Response) -> tuple:
+    """The Responses one ring entry stands for: a group's members, or the
+    request itself."""
+    return resp.request.group or (resp,)
 
 
 class ServeScheduler:
@@ -220,14 +229,35 @@ class ServeScheduler:
     # -- execution (assistant lanes) --------------------------------------
 
     def _execute(self, resp: Response) -> None:
-        """Run one request on a pool lane. Never raises: the Response is
-        the error channel, so a failing request cannot poison the lane."""
+        """Run one request, or one group, on a pool lane. Never raises: the
+        Response is the error channel, so a failing request cannot poison
+        the lane. A group runs in one ``serve.group`` span holding one
+        ``serve.request`` span per member."""
         req = resp.request
-        with span("serve.request", rid=req.rid):
+        if req.group is None:
+            with span("serve.request", rid=req.rid):
+                self._run_request(resp)
+            return
+        with span("serve.group", rid=req.rid), \
+                contextlib.ExitStack() as members:
+            for member in req.group:
+                members.enter_context(
+                    span("serve.request", rid=member.request.rid))
             self._run_request(resp)
 
     def _run_request(self, resp: Response) -> None:
         req = resp.request
+        members = _members(resp)
+
+        def parts(value):
+            # A group's work gives one part per member, in order.
+            if req.group is None:
+                return (value,)
+            if len(value) != len(members):
+                raise ValueError(f"a group of {len(members)} requests got "
+                                 f"{len(value)} parts")
+            return value
+
         first_t: Optional[float] = None
         try:
             value = req.fn(*req.args)
@@ -235,20 +265,26 @@ class ServeScheduler:
                 # Streaming work: the first yielded item stamps
                 # first-result time (TTFT for token serving); the
                 # response value is the collected stream.
-                items = []
+                streams: List[list] = [[] for _ in members]
                 for item in value:
                     if first_t is None:
                         first_t = now()
-                        resp.first_result_t = first_t
-                    items.append(item)
-                value = items
+                        for member in members:
+                            member.first_result_t = first_t
+                    for stream, part in zip(streams, parts(item)):
+                        stream.append(part)
+                values = streams
+            else:
+                values = parts(value)
             t = now()
-            if first_t is None:
-                resp.first_result_t = t
-            status = STATUS_OK
-            if req.deadline_t is not None and t > req.deadline_t:
-                status = STATUS_DEADLINE
-            resp._finish(status, value=value, complete_t=t)
+            for member, v in zip(members, values):
+                if first_t is None:
+                    member.first_result_t = t
+                status = STATUS_OK
+                deadline = member.request.deadline_t
+                if deadline is not None and t > deadline:
+                    status = STATUS_DEADLINE
+                member._finish(status, value=v, complete_t=t)
         except BaseException as exc:  # noqa: BLE001 - the future carries it
             if (req.idempotent
                     and self.retry_policy.allows(resp.attempts)
@@ -260,7 +296,9 @@ class ServeScheduler:
                 resp._retry_error = exc
                 resp._retry_pending = True
             else:
-                resp._finish(STATUS_ERROR, error=exc, complete_t=now())
+                t = now()
+                for member in members:
+                    member._finish(STATUS_ERROR, error=exc, complete_t=t)
 
     # -- scheduler loop ----------------------------------------------------
 
@@ -437,17 +475,22 @@ class ServeScheduler:
                         submits = []
                         for resp in batch:
                             req = resp.request
-                            req.admit_t = t
-                            metrics.admitted += 1
+                            members = _members(resp)
+                            for member in members:
+                                member.request.admit_t = t
+                            metrics.admitted += len(members)
                             if (req.deadline_t is not None
                                     and t > req.deadline_t):
                                 # Shed without running: the SLO violation
                                 # is surfaced, the lane time is not spent.
-                                resp._finish(STATUS_DEADLINE, complete_t=t)
-                                metrics.note_complete(resp)
+                                for member in members:
+                                    member._finish(STATUS_DEADLINE,
+                                                   complete_t=t)
+                                    metrics.note_complete(member)
                                 continue
-                            resp.attempts += 1
-                            in_flight[req.rid] = resp
+                            for member in members:
+                                member.attempts += 1
+                                in_flight[member.request.rid] = member
                             submits.append((self._execute, (resp,), {}))
                         if submits and self._dispatch(
                                 sched, submits, supervised):
@@ -507,8 +550,9 @@ class ServeScheduler:
             # Cancel whatever the stop mode left behind (queued requests on
             # drain=False, everything on a loop error).
             for resp in ingest.poll(1 << 30):
-                resp._finish(STATUS_CANCELLED, complete_t=now())
-                metrics.note_complete(resp)
+                for member in _members(resp):
+                    member._finish(STATUS_CANCELLED, complete_t=now())
+                    metrics.note_complete(member)
             # Pending retries are not re-run once the loop is exiting: they
             # finish with the failure that queued them (drain=True never
             # reaches here with a non-empty queue — the stop condition

@@ -317,6 +317,51 @@ def test_streaming_request_stamps_first_result():
     assert resp.first_result_t < resp.complete_t
 
 
+def test_submit_group_gives_each_member_its_parts():
+    def stream():
+        yield ["a0", "b0", "c0"]
+        time.sleep(0.01)
+        yield ["a1", "b1", "c1"]
+
+    with ServeScheduler(lanes=1) as server:
+        client = server.open_client("c0")
+        resps = client.submit_group(stream, 3)
+        assert [r.result(30.0) for r in resps] == [
+            ["a0", "a1"], ["b0", "b1"], ["c0", "c1"]]
+        # scalar work: one value per member
+        assert [r.result(30.0) for r in client.submit_group(
+            lambda x: (x, x + 1), 2, 5)] == [5, 6]
+    lead = resps[0].request
+    assert len({r.request.rid for r in resps}) == 3
+    for r in resps:
+        assert r.status == STATUS_OK
+        assert r.request.arrival_t == lead.arrival_t
+        assert r.request.admit_t == lead.admit_t is not None
+        assert r.request.admit_t <= r.first_result_t < r.complete_t
+    stats = server.stats()
+    assert stats["completed"] == stats["ok"] == stats["admitted"] == 5
+
+
+def test_failing_group_fails_every_member():
+    def boom():
+        yield [1, 2, 3]
+        raise KeyError("bad group")
+
+    with ServeScheduler(lanes=1) as server:
+        client = server.open_client("c0")
+        resps = client.submit_group(boom, 3)
+        short = client.submit_group(lambda: [1], 2)     # one part for two
+        for r in resps + short:
+            assert r.wait(30.0)
+    assert all(r.status == STATUS_ERROR and isinstance(r.error, KeyError)
+               for r in resps)
+    assert all(isinstance(r.error, ValueError) for r in short)
+    assert len({r.complete_t for r in resps}) == 1
+    assert server.stats()["errors"] == 5
+    with pytest.raises(ValueError):
+        ServeScheduler(lanes=0).open_client("c1").submit_group(lambda: [], 0)
+
+
 def test_stop_drains_queued_requests():
     server = ServeScheduler(lanes=1).start()
     client = server.open_client("c0")
@@ -538,6 +583,145 @@ def test_serve_programs_match_plain_jits(arch):
     np.testing.assert_allclose(
         np.asarray(jnp.concatenate([lg for _, lg in served], 1)),
         np.asarray(ref), rtol=0.05, atol=0.05)
+
+
+# ---------------------------------------------------------------------------
+# request groups: equal-shape queued requests served as one batch
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def tiny():
+    from repro.configs import get_config
+    from repro.launch.serve import load
+
+    return load(get_config("relic_tiny", smoke=True))
+
+
+def _prompts(model, shapes, seed=0):
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    return [jnp.asarray(rng.integers(0, model.cfg.vocab_size, shape),
+                        jnp.int32) for shape in shapes]
+
+
+def _served(resp):
+    out = resp.result()
+    return (np.concatenate([np.asarray(tok) for tok, _ in out], 1),
+            np.concatenate([np.asarray(lg) for _, lg in out], 1))
+
+
+def _groups(resps):
+    """The queue's groups, as the members' shared completion times show
+    them: lists of indices, in order."""
+    groups = []
+    for i, r in enumerate(resps):
+        if groups and resps[groups[-1][0]].complete_t == r.complete_t:
+            groups[-1].append(i)
+        else:
+            groups.append([i])
+    return groups
+
+
+@pytest.mark.parametrize("keys,rows,max_rows,fits,want", [
+    ("aaaa", [2, 2, 2, 2], 8, None, [[0, 1, 2, 3]]),
+    ("aaba", [2, 2, 2, 2], 8, None, [[0, 1], [2], [3]]),
+    ("aaaaa", [2, 2, 2, 2, 2], 4, None, [[0, 1], [2, 3], [4]]),
+    ("aaaa", [2, 6, 2, 2], 4, None, [[0], [1], [2, 3]]),
+    ("aaaa", [2, 2, 2, 2], None, None, [[0], [1], [2], [3]]),
+    ("aaaa", [2, 2, 2, 2], 8, 4, [[0, 1], [2, 3]]),
+], ids=["equal", "shapes", "row_cap", "over_cap_alone", "no_cap", "memory"])
+def test_plan_groups(keys, rows, max_rows, fits, want):
+    from repro.launch.serve import plan_groups
+
+    fit = (lambda r: True) if fits is None else (lambda r: r <= fits)
+    assert plan_groups(list(keys), rows, max_rows, fit) == want
+
+
+def test_equal_shape_requests_serve_as_one_group(tiny):
+    from repro.launch.serve import serve
+
+    model, params = tiny
+    prompts = _prompts(model, [(2, 5)] * 4)
+    merged = serve(model, params, prompts, gen=4, cache_len=12, max_rows=8)
+    assert _groups(merged) == [[0, 1, 2, 3]]
+    assert len({r.request.admit_t for r in merged}) == 1
+    for prompt, resp in zip(prompts, merged):
+        (alone,) = serve(model, params, [prompt], gen=4, cache_len=12)
+        toks, logits = _served(resp)
+        toks_alone, logits_alone = _served(alone)
+        assert toks.shape == (2, 4)
+        np.testing.assert_array_equal(toks, toks_alone)
+        np.testing.assert_allclose(logits, logits_alone, rtol=0.05,
+                                   atol=0.05)
+
+
+def test_unequal_shapes_never_merge(tiny):
+    from repro.launch.serve import serve
+
+    model, params = tiny
+    prompts = _prompts(model, [(2, 5), (2, 6), (1, 5), (2, 5)])
+    resps = serve(model, params, prompts, gen=3, cache_len=12, max_rows=64)
+    assert _groups(resps) == [[0], [1], [2], [3]]
+    assert [_served(r)[0].shape for r in resps] == [(2, 3), (2, 3), (1, 3),
+                                                    (2, 3)]
+    # each decodes from its own prompt length
+    (alone,) = serve(model, params, prompts[1:2], gen=3, cache_len=12)
+    np.testing.assert_array_equal(_served(resps[1])[0], _served(alone)[0])
+
+
+def test_row_cap_splits_groups_in_queue_order(tiny):
+    from repro.launch.serve import serve
+
+    model, params = tiny
+    prompts = _prompts(model, [(2, 5), (2, 5), (2, 5), (5, 5), (2, 5)])
+    resps = serve(model, params, prompts, gen=3, cache_len=8, max_rows=4)
+    assert _groups(resps) == [[0, 1], [2], [3], [4]]
+    assert [r.status for r in resps] == [STATUS_OK] * 5
+
+
+def test_nothing_merges_on_a_chip_not_in_the_table(tiny):
+    from repro.launch.peaks import ridge_rows
+    from repro.launch.serve import serve
+
+    model, params = tiny
+    assert ridge_rows("cpu") is None
+    assert ridge_rows("TPU v5 lite") == 240
+    resps = serve(model, params, _prompts(model, [(2, 5)] * 3), gen=3,
+                  cache_len=8)
+    assert _groups(resps) == [[0], [1], [2]]
+    assert resps[0].complete_t <= resps[1].first_result_t
+
+
+def test_queue_is_iterated_once_after_its_programs_compile(tiny):
+    """serve() reads the queue by len() and indexing, compiles every
+    group's programs, then iterates it once: nothing compiles after."""
+    from jax import monitoring
+    from jax._src.monitoring import unregister_event_duration_listener
+
+    from repro.launch.serve import serve
+
+    model, params = tiny
+    compiles, at_iter = [], []
+
+    def on_duration(event, seconds, **_):
+        if event.endswith("backend_compile_duration"):
+            compiles.append(event)
+
+    class Queue(list):
+        def __iter__(self):
+            at_iter.append(len(compiles))
+            return super().__iter__()
+
+    queue = Queue(_prompts(model, [(2, 7)] * 3 + [(1, 7)], seed=3))
+    monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        resps = serve(model, params, queue, gen=3, cache_len=10, max_rows=4)
+    finally:
+        unregister_event_duration_listener(on_duration)
+    assert _groups(resps) == [[0, 1], [2], [3]]
+    assert len(at_iter) == 1
+    assert at_iter[0] > 0 and len(compiles) == at_iter[0]
 
 
 # ---------------------------------------------------------------------------

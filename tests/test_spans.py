@@ -156,9 +156,13 @@ def test_spans_on_land_in_the_profiler_trace_with_their_ids(tmp_path,
     serving = [ev for ev in events if ev[0].startswith("serve.")]
     assert {ev[4]["rid"] for ev in serving} == {rid}
     (request,) = [ev for ev in serving if ev[0] == "serve.request"]
+    # a request served alone is a group of one: its lane task's span
+    (group,) = [ev for ev in serving if ev[0] == "serve.group"]
+    assert group[2] <= request[2] and request[3] <= group[3]
     for ev in serving:
         assert ev[1] == request[1]                   # the lane's thread
-        assert request[2] <= ev[2] and ev[3] <= request[3]
+        if ev is not group:
+            assert request[2] <= ev[2] and ev[3] <= request[3]
     steps = sorted(ev[4]["pos"] for ev in serving if ev[0] == "serve.step")
     assert steps == [4, 5]
     assert Request.next_rid() > rid

@@ -32,7 +32,7 @@ from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.relic_matmul import relic_matmul, relic_matmul_gated
 from repro.kernels.ssd import ssd_bhtp
 from repro.kernels.wkv6 import wkv6_bhtk
-from repro.launch.serve import cache_programs
+from repro.launch.serve import cache_programs, group_programs
 from repro.launch.steps import make_train_state, make_train_step
 from repro.models import build_model
 from repro.optim import OptConfig
@@ -185,6 +185,28 @@ def test_phi3_serve_keeps_the_cache_in_place(one_chip, step):
     slab = batch * cache_len * cfg.n_kv_heads * cfg.resolved_head_dim * 2
     assert _copies_of_at_least(compiled.as_text(), slab) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+@pytest.mark.parametrize("prompt_len", [64, 480])
+def test_phi3_merged_group_fits_and_keeps_the_cache_in_place(one_chip,
+                                                             prompt_len):
+    """The phi3 serve cells' 4 queued [4, P] requests merge into one
+    16-row group (under the v5e's ridge of 240 rows): its prefill and
+    decode step fit one chip with the weights and the 16-row cache, and
+    copy no cache-sized array."""
+    cfg = get_config("phi3_mini_3p8b").replace(param_dtype="bfloat16")
+    model = build_model(cfg)
+    params = _placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                     one_chip)
+    _, prefill, step, join, split = group_programs(
+        model, params, 4, jax.ShapeDtypeStruct((4, prompt_len), jnp.int32),
+        None, 512)
+    slab = 16 * 512 * cfg.n_kv_heads * cfg.resolved_head_dim * 2
+    for compiled in (prefill, step):
+        ma = compiled.memory_analysis()
+        assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BUDGET
+        assert _copies_of_at_least(compiled.as_text(), slab) == []
+    assert join is not None and split is not None
 
 
 def _dsv3_serve_programs(one_chip, step, batch, cache_len, prompt_len):
