@@ -23,14 +23,13 @@
           relic-pool per-task overhead at lanes 1/2/4 against the
           single-lane relic pair (lanes=1 must not tax the pair), plus
           the chunked workloads striped over the lanes
-  skew  — skew-resistance A/B: every workload under power-law task
-          costs, chunked over small-ring pools with RelicPool dynamic
-          rebalancing ON vs OFF (static PR 5 striping), lanes 2/4 —
-          the derived ``vs_static`` is the headline of PR 6
-  faults — robustness: supervision on/off overhead and kill-a-lane
-          detection latency / recovery time / throughput dip at lanes
-          2/4 with respawn, loss accounting asserted exact (no
-          ``speedup=`` on these rows — they gate on invariants)
+  skew  — skew resistance: every workload under power-law task costs,
+          chunked over small-ring self-rebalancing pools at lanes 2/4,
+          speedup against the skewed serial baseline
+  faults — robustness: the supervised pool's per-task cost and
+          kill-a-lane detection latency / recovery time / throughput
+          dip at lanes 2/4 with respawn, loss accounting asserted exact
+          (no ``speedup=`` on these rows — they gate on invariants)
   roofline — summary of the dry-run artifacts, if present
 
 Output: ``name,us_per_call,derived`` CSV per line on stdout (unchanged
@@ -48,7 +47,7 @@ sessions (see compare_against). ``--only`` takes one section or a
 comma-separated list (``--only paper,scaling``).
 Usage: PYTHONPATH=src python -m benchmarks.run [--iters 1000]
        [--only paper,scaling] [--json BENCH_new.json]
-       [--compare BENCH_pr4.json]
+       [--compare BENCH_old.json]
 """
 
 from __future__ import annotations
@@ -476,22 +475,18 @@ def run_scaling(iters: int, em: Emitter):
 
 
 def run_skew(iters: int, em: Emitter):
-    """The skew-resistance A/B: every workload under a power-law task-cost
+    """Skew resistance: every workload under a power-law task-cost
     profile (``skew=1.0``: heaviest instance repeats its kernel n times,
     rank r ~ r**-1 of that), worksharing-chunked at grain=1 over a
     deliberately small-ring pool (capacity=4, n=16 instances — so burst
-    remainders exist and the sweep actually runs), with RelicPool's
-    dynamic rebalancing ON vs OFF (``rebalance=False`` == the PR 5 static
-    striping) at lanes 2 and 4.
+    remainders exist and the sweep actually runs; the pool re-stripes
+    them and hands work to idle lanes) at lanes 2 and 4.
 
-    Rows: ``skew/<workload>/serial`` (the skewed serial baseline),
-    ``skew/<workload>/lanes<N>/rebalance`` and ``.../static``, each
-    oracle-checked before timing. The rebalance rows carry ``vs_static``
-    (its speedup against the static config's, same lanes) — the headline
-    derived value: positive means dynamic load balancing beat static
-    striping under skewed costs. Same measurement discipline as the paper
-    table: noise-floor timing, several full passes, speedups paired
-    within a pass, best pass kept.
+    Rows: ``skew/<workload>/serial`` (the skewed serial baseline) and
+    ``skew/<workload>/lanes<N>``, each oracle-checked before timing, with
+    the speedup over the serial baseline. Same measurement discipline as
+    the paper table: noise-floor timing, several full passes, speedups
+    paired within a pass, best pass kept.
     """
     from benchmarks.schedulers import timeit_us_floor
     from repro.core.schedulers import make_scheduler
@@ -505,7 +500,6 @@ def run_skew(iters: int, em: Emitter):
     n_instances = 16
     skew = 1.0
     lane_counts = [2, 4]
-    modes = [("rebalance", True), ("static", False)]
 
     workloads = {name: make_workload(name, n_instances=n_instances,
                                      skew=skew)
@@ -520,23 +514,21 @@ def run_skew(iters: int, em: Emitter):
             key = f"skew/{wname}/serial"
             floor[key] = min(floor.get(key, float("inf")), us_serial_p)
             for lanes in lane_counts:
-                for mode, rebalance in modes:
-                    sched = make_scheduler("relic-pool", lanes=lanes,
-                                           capacity=capacity,
-                                           rebalance=rebalance)
-                    with TaskScope(sched) as scope:
-                        def run(w=w, scope=scope):
-                            return w.chunked(scope, grain=1)
+                sched = make_scheduler("relic-pool", lanes=lanes,
+                                       capacity=capacity)
+                with TaskScope(sched) as scope:
+                    def run(w=w, scope=scope):
+                        return w.chunked(scope, grain=1)
 
-                        if p == 0:
-                            w.check(run())     # verified before timing
-                        key = f"skew/{wname}/lanes{lanes}/{mode}"
-                        us_p = timeit_us_floor(run, reps, warmup, rounds=3)
-                        floor[key] = min(floor.get(key, float("inf")), us_p)
-                        speedup[key] = max(speedup.get(key, 0.0),
-                                           us_serial_p / us_p)
+                    if p == 0:
+                        w.check(run())         # verified before timing
+                    key = f"skew/{wname}/lanes{lanes}"
+                    us_p = timeit_us_floor(run, reps, warmup, rounds=3)
+                    floor[key] = min(floor.get(key, float("inf")), us_p)
+                    speedup[key] = max(speedup.get(key, 0.0),
+                                       us_serial_p / us_p)
 
-    em.header("skew: power-law task costs, rebalance vs static striping "
+    em.header("skew: power-law task costs over self-rebalancing pools "
               f"(chunked grain=1, n={n_instances}, skew={skew}, "
               f"capacity={capacity}; oracle-checked; floors + best "
               f"same-pass speedups over {passes} passes)")
@@ -544,14 +536,8 @@ def run_skew(iters: int, em: Emitter):
         em.row(f"skew/{wname}/serial", floor[f"skew/{wname}/serial"],
                f"n={n_instances};skew={skew};speedup=1.000;oracle=ok")
         for lanes in lane_counts:
-            sp_static = speedup[f"skew/{wname}/lanes{lanes}/static"]
-            for mode, _ in modes:
-                key = f"skew/{wname}/lanes{lanes}/{mode}"
-                derived = f"speedup={speedup[key]:.3f};oracle=ok"
-                if mode == "rebalance":
-                    derived += (f";vs_static="
-                                f"{speedup[key] / sp_static - 1:+.1%}")
-                em.row(key, floor[key], derived)
+            key = f"skew/{wname}/lanes{lanes}"
+            em.row(key, floor[key], f"speedup={speedup[key]:.3f};oracle=ok")
 
 
 def run_stream(iters: int, em: Emitter):
@@ -960,12 +946,10 @@ def run_faults(iters: int, em: Emitter):
     axis, not a speedup claim — the gate for these rows is the asserted
     loss accounting, not a trajectory ratio):
 
-    * ``faults/overhead`` — supervision on vs off: submit_batch+wait of
-      no-op bursts through a 2-lane pool with ``supervise=True`` (the
-      default: liveness probes every 1024 producer spins, heartbeat
-      bookkeeping on check_lanes) against ``supervise=False`` (the exact
-      pre-PR8 spin loops). The on/off ratio is the price of bounded
-      waits; it should be within noise.
+    * ``faults/overhead`` — the per-task cost of the supervised pool:
+      submit_batch+wait of no-op bursts through a 2-lane pool (liveness
+      probes every 1024 producer spins, heartbeat bookkeeping on
+      check_lanes).
     * ``faults/kill/lanesN`` — kill-a-lane (lanes 2 and 4, respawn on):
       a seeded KillSwitch takes lane 1 down with its first burst
       in-flight. Measured: detection latency (death -> check_lanes
@@ -997,29 +981,22 @@ def run_faults(iters: int, em: Emitter):
     n = max(iters, 200)
     reps = 5
 
-    em.header("faults: supervision overhead + kill-a-lane detection/"
+    em.header("faults: supervised pool cost + kill-a-lane detection/"
               f"recovery (n={n} tasks/burst, {reps} bursts, respawn on)")
 
-    # -- supervision overhead: on vs off, identical submit pattern --------
-    overhead_us = {}
-    for supervise in (True, False):
-        pool = RelicPool(lanes=2, capacity=256, supervise=supervise).start()
-        batch = [(noop, (), {})] * n
-        pool.submit_batch(batch)           # warm the lanes
+    # -- the supervised pool's per-task cost ------------------------------
+    pool = RelicPool(lanes=2, capacity=256).start()
+    batch = [(noop, (), {})] * n
+    pool.submit_batch(batch)               # warm the lanes
+    pool.wait()
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        pool.submit_batch(batch)
         pool.wait()
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            pool.submit_batch(batch)
-            pool.wait()
-        dt = time.perf_counter() - t0
-        pool.shutdown()
-        tag = "on" if supervise else "off"
-        overhead_us[tag] = dt / (reps * n) * 1e6
-        em.row(f"faults/overhead/supervise_{tag}", overhead_us[tag],
-               f"lanes=2;n={n};reps={reps}")
-    em.comment(f"supervision overhead: x"
-               f"{overhead_us['on'] / max(overhead_us['off'], 1e-9):.3f} "
-               "(on/off; 1.0 = free)")
+    dt = time.perf_counter() - t0
+    pool.shutdown()
+    em.row("faults/overhead/lanes2", dt / (reps * n) * 1e6,
+           f"lanes=2;n={n};reps={reps}")
 
     # -- kill-a-lane: detection, recovery, throughput dip -----------------
     def timed_run(lanes, kill):
@@ -1200,7 +1177,7 @@ def main(argv=None) -> None:
                          f"run (default all): {','.join(SECTIONS)}")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write per-section results (µs + speedups) to "
-                         "this JSON file, e.g. BENCH_pr2.json")
+                         "this JSON file, e.g. BENCH_new.json")
     ap.add_argument("--compare", default=None, metavar="BASELINE.json",
                     help="compare this run's rows against an earlier BENCH "
                          "file; any row slower by more than --compare-tol "

@@ -13,6 +13,13 @@ Faithful port of the paper's design to a Python host runtime:
     ``wake_up_hint()`` / ``sleep_hint()`` to park the assistant across long
     serial sections instead of a hybrid spin-then-sleep heuristic.
 
+Every producer spin loop is bounded: it probes the assistant's liveness
+every ``_PROBE_EVERY_SPINS`` rounds and raises :class:`RelicDeadError`
+instead of spinning on a counter nothing will ever advance. A
+:class:`repro.core.relic_pool.RelicPool` lane of a multi-lane pool also gets
+a handoff ring, which the one assistant loop drains only while its primary
+ring is empty; the plain pair has none.
+
 On TPU the same schedule is realized by the DMA/compute lanes inside the
 Pallas kernels (see ``repro.kernels.relic_matmul``) and by the ppermute ring
 in ``repro.core.collective_matmul``; this module is the host-scale instance,
@@ -33,6 +40,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable, Optional, Sequence, Tuple
 
 from repro.core.spsc import DEFAULT_CAPACITY, SpscRing
+from repro.runtime.config import _default_spin_yield, resolve_spin_pause_every
 
 # Task protocol: the ring carries bare ``fn, args`` pairs striped across two
 # slots (``push2``/flattened ``push_many``) — no per-task wrapper object, so
@@ -51,7 +59,7 @@ class RelicDeadError(RuntimeError):
 
     Raised by the producer's bounded-wait liveness probes (``_barrier``,
     ``_push_spin``, ``_push_flat`` check ``assistant.is_alive()`` every
-    ``_PROBE_EVERY_SPINS`` spin rounds when ``RELIC_SUPERVISE`` is on)
+    ``_PROBE_EVERY_SPINS`` spin rounds)
     instead of spinning forever on a counter that can no longer advance.
     Carries the diagnostics a supervisor needs: which lane, how many tasks
     were submitted/completed, and how many in-flight tasks are lost with
@@ -109,13 +117,6 @@ class RelicStats:
     first_error_handoff_index: Optional[int] = None
 
 
-# Spin-cadence resolution lives with the other env-var knobs in
-# ``repro.runtime.config``; re-exported here because this module is where
-# callers (tests, benchmarks, docs) historically found it.
-from repro.runtime.config import (_default_spin_yield,
-                                  resolve_spin_pause_every,
-                                  resolve_supervise_config)
-
 SPIN_PAUSE_EVERY = _default_spin_yield()
 
 # Liveness-probe cadence for the producer's spin loops: one
@@ -124,6 +125,12 @@ SPIN_PAUSE_EVERY = _default_spin_yield()
 # while the probe cost is amortized to noise; the clean fast paths
 # (submit-with-room, the assistant drain) never reach a probe at all.
 _PROBE_EVERY_SPINS = 1024
+
+# Idle assistant iterations between polls of a lane's handoff ring: an
+# empty-ring pop still pays a cross-thread index read, and the handoff ring
+# is the lane's cold path by construction (the pool fills it only when the
+# primaries are backed up).
+_HANDOFF_POLL_EVERY = 8
 
 
 class Relic:
@@ -142,28 +149,23 @@ class Relic:
     """
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY, start_awake: bool = False,
-                 name: str = "relic-assistant", handoff: bool = False):
+                 name: str = "relic-assistant"):
         if capacity <= 0:
             raise ValueError(f"capacity must be positive, got {capacity}")
         # Two ring slots per task (the fn, args stripe — see the task
         # protocol note above), so `capacity` stays a task count.
         self._ring = SpscRing(2 * capacity)
         self._push2 = self._ring.push2      # pre-bound: the submit hot path
-        # Optional victim-cooperative handoff ring (RelicPool rebalancing):
-        # a second, equally-bounded SPSC ring the *pool producer* fills only
-        # when this lane's primary is backed up, and the assistant drains
-        # only when the primary is empty. Still strictly 1P1C per ring —
-        # same producer thread, same consumer thread, two rings. The plain
-        # pair never allocates it and keeps its original assistant loop.
-        self._oring: Optional[SpscRing] = SpscRing(2 * capacity) if handoff else None
+        # Victim-cooperative handoff ring: a second, equally-bounded SPSC
+        # ring the *pool producer* fills only when this lane's primary is
+        # backed up, and the assistant drains only when the primary is
+        # empty. Still strictly 1P1C per ring — same producer thread, same
+        # consumer thread, two rings. None for the plain pair and a
+        # one-lane pool; a multi-lane RelicPool attaches one per lane
+        # before start().
+        self._oring: Optional[SpscRing] = None
         self._name = name                   # assistant thread name (pool lanes)
         self._spin_pause_every = resolve_spin_pause_every()
-        # Bounded waits (PR 8): with RELIC_SUPERVISE on (the default) the
-        # producer's spin loops probe assistant liveness every
-        # _PROBE_EVERY_SPINS rounds and raise RelicDeadError instead of
-        # hanging; 0 disables every probe (the pre-supervision spins).
-        self._probe_every = (_PROBE_EVERY_SPINS
-                             if resolve_supervise_config().supervise else 0)
         # Opt-in chaos hook (repro.runtime.chaos): when set, the assistant
         # calls it once per drained burst (with the burst's task count) and
         # exits abruptly — simulated thread death — when it returns True.
@@ -187,10 +189,8 @@ class Relic:
         if self._assistant is not None:
             raise RelicUsageError("Relic runtime already started")
         self._main_ident = threading.get_ident()
-        target = (self._assistant_loop if self._oring is None
-                  else self._assistant_loop_handoff)
         self._assistant = threading.Thread(
-            target=target, name=self._name, daemon=True
+            target=self._assistant_loop, name=self._name, daemon=True
         )
         self._assistant.start()
         return self
@@ -276,7 +276,6 @@ class Relic:
                 stats.submitted += pushed // 2
         spins = 0
         pause_every = self._spin_pause_every
-        probe_every = self._probe_every
         while pos < n:
             if spins == 0:
                 # Advisory hints must not deadlock a full-ring burst: the
@@ -286,7 +285,7 @@ class Relic:
             spins += 1
             if spins % pause_every == 0:
                 time.sleep(0)
-            if probe_every and spins % probe_every == 0:
+            if spins % _PROBE_EVERY_SPINS == 0:
                 self._probe_alive()   # a dead consumer never frees a slot
             pushed = ring.push_many(flat, pos, n)
             if pushed:
@@ -299,7 +298,6 @@ class Relic:
         """Full-ring slow path for submit(): bounded ring is the backpressure."""
         spins = 0
         pause_every = self._spin_pause_every
-        probe_every = self._probe_every
         while not self._push2(fn, args):
             if spins == 0:
                 # Hints are advisory (§VI-B): a full ring with a parked
@@ -310,7 +308,7 @@ class Relic:
             spins += 1
             if spins % pause_every == 0:
                 time.sleep(0)  # the Python analogue of `pause`: yield, no park
-            if probe_every and spins % probe_every == 0:
+            if spins % _PROBE_EVERY_SPINS == 0:
                 self._probe_alive()   # a dead consumer never frees a slot
 
     def wait(self) -> None:
@@ -352,8 +350,8 @@ class Relic:
         completed. RelicPool barriers each lane through this so it can map
         lane-local error indexes to pool-global submission order *before*
         the error state is consumed. Raises nothing — except
-        ``RelicDeadError`` when supervision is on and the assistant thread
-        died with the barrier outstanding (the wait-liveness contract,
+        ``RelicDeadError`` when the assistant thread died with the barrier
+        outstanding (the wait-liveness contract,
         docs/schedulers.md): spinning on a counter whose only writer is
         gone would never return."""
         target = self.stats.submitted
@@ -364,12 +362,11 @@ class Relic:
             self._awake.set()
         spins = 0
         pause_every = self._spin_pause_every
-        probe_every = self._probe_every
         while self._completed < target:
             spins += 1
             if spins % pause_every == 0:
                 time.sleep(0)
-            if probe_every and spins % probe_every == 0:
+            if spins % _PROBE_EVERY_SPINS == 0:
                 self._probe_alive()
         self.stats.completed = self._completed
 
@@ -426,11 +423,22 @@ class Relic:
     # ---------------------------------------------------------- assistant side
 
     def _assistant_loop(self) -> None:
+        """Drain the primary ring; while it is empty, help with the lane's
+        handoff ring (when it has one) before spinning or parking.
+
+        Primary work always drains first, so the lane's own FIFO is
+        untouched and handed-off tasks run at lane-idle priority. Which
+        ring a burst came from is decided once per burst: it picks the
+        per-task loop, and so which completion counter a task advances
+        and which first-error index field records its failure."""
         ring = self._ring
         stats = self.stats
         pop_many = ring.pop_many
+        opop_many = None if self._oring is None else self._oring.pop_many
         spins = 0
         pause_every = self._spin_pause_every
+        poll_countdown = 1   # first idle pass polls the handoff ring at once
+        c_ovf = 0            # handoff-ring completions (assistant-only)
         while True:
             # Drain the whole burst before re-checking hints or shutdown: one
             # _head publication per burst (pop_many), not one per task. The
@@ -438,120 +446,81 @@ class Relic:
             # whole number of fn,args pairs, so an unbounded pop keeps the
             # stripe aligned (an odd max_items could split a pair).
             batch = pop_many()
-            if not batch:
-                if self._shutdown:
-                    return
-                if not self._awake.is_set():
-                    # sleep_hint() was given: park on the event (OS suspension)
-                    # instead of burning the core. wake_up_hint() releases us.
-                    stats.parks += 1
-                    self._awake.wait()
-                    continue
-                stats.assistant_empty_spins += 1
-                spins += 1
-                if spins % pause_every == 0:
-                    time.sleep(0)  # `pause`-like: yield the GIL, stay runnable
-                continue
-            spins = 0
-            if self._chaos_kill is not None and self._chaos_kill(len(batch) // 2):
-                return  # injected thread death: the popped burst is lost
-            completed = self._completed    # assistant-only writer: no race
-            for i in range(0, len(batch), 2):
-                try:
-                    batch[i](*batch[i + 1])
-                except BaseException as e:  # surfaced at the next wait()
-                    stats.task_errors += 1
-                    if stats.last_error is None:
-                        # First error wins (the SPI contract shared by every
-                        # substrate — see docs/schedulers.md); later failures
-                        # only bump task_errors. The submission index lets
-                        # RelicPool order first-errors across lanes.
-                        stats.first_error_index = completed
-                        stats.last_error = e
-                # Atomic per-task publication of completion (store of a
-                # local, not a read-modify-write) so the producer's barrier
-                # observes progress early.
-                completed += 1
-                self._completed = completed
-
-    def _assistant_loop_handoff(self) -> None:
-        """Assistant loop for a lane with a handoff ring (RelicPool
-        rebalancing). Identical to ``_assistant_loop`` except: when the
-        primary ring is empty, the assistant pulls from its handoff ring
-        before parking/spinning — the victim-cooperative half of the
-        pool's skew resistance. Primary work always drains first, so the
-        lane's own FIFO is untouched; handoff tasks run at lane-idle
-        priority, each ring still strictly one-producer/one-consumer.
-        Kept as a separate loop so the plain pair's drain stays
-        byte-for-byte the paper's two-thread hot path."""
-        ring = self._ring
-        oring = self._oring
-        stats = self.stats
-        pop_many = ring.pop_many
-        opop_many = oring.pop_many
-        spins = 0
-        pause_every = self._spin_pause_every
-        ovf_poll_every = 8  # idle iterations between overflow-ring polls
-        ovf_countdown = 1   # first idle pass polls immediately
-        c_main = 0      # primary-ring completions (local; assistant-only)
-        c_ovf = 0       # handoff-ring completions
-        while True:
             from_ovf = False
-            batch = pop_many()
             if not batch:
-                # Primary idle: help with handed-off (rebalanced) work.
-                # An empty-ring pop still pays a cross-thread index read,
-                # so the steady-state idle spin polls the handoff ring
-                # only every few iterations (it is the lane's *cold* path
-                # by construction — the producer fills it only when
-                # primaries are backed up). Shutdown and park force the
-                # poll: both must observe a drained handoff ring first.
-                ovf_countdown -= 1
-                if (ovf_countdown <= 0 or self._shutdown
-                        or not self._awake.is_set()):
-                    ovf_countdown = ovf_poll_every
-                    batch = opop_many()
-                    from_ovf = True
+                if opop_many is not None:
+                    # Shutdown and park force the poll: both must observe
+                    # a drained handoff ring first.
+                    poll_countdown -= 1
+                    if (poll_countdown <= 0 or self._shutdown
+                            or not self._awake.is_set()):
+                        poll_countdown = _HANDOFF_POLL_EVERY
+                        batch = opop_many()
+                        from_ovf = True
                 if not batch:
                     if self._shutdown:
-                        return          # both rings drained
+                        return          # every ring drained
                     if not self._awake.is_set():
+                        # sleep_hint() was given: park on the event (OS
+                        # suspension) instead of burning the core.
+                        # wake_up_hint() releases us.
                         stats.parks += 1
                         self._awake.wait()
                         continue
                     stats.assistant_empty_spins += 1
                     spins += 1
                     if spins % pause_every == 0:
-                        time.sleep(0)
+                        time.sleep(0)  # `pause`-like: yield, stay runnable
                     continue
             spins = 0
             if self._chaos_kill is not None and self._chaos_kill(len(batch) // 2):
                 return  # injected thread death: the popped burst is lost
+            completed = self._completed    # assistant-only writer: no race
+            if not from_ovf:
+                for i in range(0, len(batch), 2):
+                    try:
+                        batch[i](*batch[i + 1])
+                    except BaseException as e:  # surfaced at the next wait()
+                        # Index among primary-ring completions.
+                        self._record_error(e, completed - c_ovf, False)
+                    # Atomic per-task publication of completion (store of a
+                    # local, not a read-modify-write) so the producer's
+                    # barrier observes progress early.
+                    completed += 1
+                    self._completed = completed
+                continue
             for i in range(0, len(batch), 2):
                 try:
                     batch[i](*batch[i + 1])
                 except BaseException as e:
-                    stats.task_errors += 1
-                    if stats.last_error is None:
-                        # First error wins, as in the primary loop; which
-                        # ring carried the task decides which index field
-                        # RelicPool maps through (seq log vs handoff log).
-                        if from_ovf:
-                            stats.first_error_handoff_index = c_ovf
-                        else:
-                            stats.first_error_index = c_main
-                        stats.last_error = e
-                if from_ovf:
-                    c_ovf += 1
-                    # Publication order matters for _trim_runs' racy reads:
-                    # _completed_ovf first, then the total — a reader that
-                    # takes the total first and the ovf count second can
-                    # only *under*count primary completions (total - ovf),
-                    # so seq-log trimming stays conservative.
-                    self._completed_ovf = c_ovf
-                else:
-                    c_main += 1
-                self._completed = c_main + c_ovf
+                    self._record_error(e, c_ovf, True)
+                c_ovf += 1
+                completed += 1
+                # Publication order matters for _trim_runs' racy reads:
+                # _completed_ovf first, then the total — a reader that
+                # takes the total first and the handoff count second can
+                # only *under*count primary completions (total - handoff),
+                # so seq-log trimming stays conservative.
+                self._completed_ovf = c_ovf
+                self._completed = completed
+
+    def _record_error(self, e: BaseException, index: int,
+                      handoff: bool) -> None:
+        """Count a task error; keep it if it is the first since the last
+        ``wait()``. First error wins (the SPI contract shared by every
+        substrate — see docs/schedulers.md); later failures only bump
+        ``task_errors``. ``index`` counts completions on the ring that
+        carried the task, and that ring picks the field it lands in, so
+        RelicPool can map it back to submission order (seq log vs handoff
+        log)."""
+        stats = self.stats
+        stats.task_errors += 1
+        if stats.last_error is None:
+            if handoff:
+                stats.first_error_handoff_index = index
+            else:
+                stats.first_error_index = index
+            stats.last_error = e
 
     # ------------------------------------------------------------- context mgr
 

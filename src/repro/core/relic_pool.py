@@ -26,11 +26,11 @@ What the pool adds on top of the lanes:
   non-blocking pass hands every lane what its ring has room for, then
   the remainders are swept round-robin, so here too a wedged lane never
   starves the shards the other lanes already have room to run.
-* **Skew resistance (dynamic load balancing, PR 6).** Static striping
-  pins a task to its lane forever — exactly where irregular (power-law
+* **Skew resistance (dynamic load balancing).** Static striping would
+  pin a task to its lane forever — exactly where irregular (power-law
   cost) workloads bleed speedup when one lane wedges behind a long task.
-  With ``rebalance=True`` (the default for multi-lane pools) two
-  mechanisms fix that without touching any hot path or SPSC invariant:
+  Every pool of more than one lane therefore rebalances, with two
+  mechanisms that touch no hot path and no SPSC invariant:
   (1) *re-striping* — a burst remainder the sweep cannot place in its
   own lane is re-dealt, producer-side, to lanes with room; (2) a
   *victim-cooperative handoff ring* per lane — a second bounded SPSC
@@ -38,12 +38,11 @@ What the pool adds on top of the lanes:
   lane's assistant drains only when its primary is idle. Every ring
   stays strictly one-producer/one-consumer (the pool's single producer
   pushes, that lane's single assistant pops); there is still no MPMC
-  structure and no lock anywhere. ``rebalance=False`` reproduces the
-  static PR 5 pool bit-for-bit.
-* **Lane supervision & graceful degradation (PR 8).** With
-  ``RELIC_SUPERVISE`` on (the default) every producer slow path is a
-  *bounded* wait: the spin loops periodically probe assistant liveness,
-  so a lane whose thread died is **quarantined** — pulled out of
+  structure and no lock anywhere. A one-lane pool has nowhere to re-deal
+  to and has no handoff ring.
+* **Lane supervision & graceful degradation.** Every producer slow path
+  is a *bounded* wait: the spin loops periodically probe assistant
+  liveness, so a lane whose thread died is **quarantined** — pulled out of
   striping, its in-flight tasks deterministically accounted as lost
   (:class:`LaneFailure`), the event surfaced at ``wait()`` as
   :class:`LaneFailedError` — instead of hanging the producer forever.
@@ -88,7 +87,7 @@ from typing import Any, Callable, Iterable, List, Optional, Tuple
 
 from repro.core.relic import (_PROBE_EVERY_SPINS, Relic, RelicDeadError,
                               RelicStats, RelicUsageError, flatten_tasks)
-from repro.core.spsc import DEFAULT_CAPACITY
+from repro.core.spsc import DEFAULT_CAPACITY, SpscRing
 from repro.runtime.config import resolve_supervise_config
 from repro.runtime.fault import LaneSupervisor
 
@@ -247,15 +246,14 @@ class RelicPool:
     """
 
     def __init__(self, lanes: int = 2, capacity: int = DEFAULT_CAPACITY,
-                 start_awake: bool = False, rebalance: bool = True,
-                 respawn: bool = False, supervise: Optional[bool] = None,
+                 start_awake: bool = False, respawn: bool = False,
                  heartbeat_ms: Optional[float] = None):
         if lanes <= 0:
             raise ValueError(f"lanes must be positive, got {lanes}")
         self._n = lanes
         self._capacity = capacity
         self._start_awake = start_awake
-        # Graceful degradation (PR 8): a lane whose assistant thread died is
+        # Graceful degradation: a lane whose assistant thread died is
         # *quarantined* — removed from striping, its in-flight tasks
         # accounted as lost (see _quarantine_lane), the event surfaced at
         # the next wait() as LaneFailedError. With ``respawn=True`` a fresh
@@ -263,29 +261,18 @@ class RelicPool:
         # pair's non-restartable contract is amended at *pool scope only* —
         # an individual Relic still never restarts, the pool replaces it.
         self._respawn = bool(respawn)
-        # kwargs > RELIC_SUPERVISE / RELIC_HEARTBEAT_MS env > defaults.
-        sup_cfg = resolve_supervise_config(supervise=supervise,
-                                           heartbeat_ms=heartbeat_ms)
-        self._supervise = sup_cfg.supervise
-        self._heartbeat_s = sup_cfg.heartbeat_ms / 1000.0
-        # Skew resistance (PR 6): with ``rebalance`` on, a burst remainder
-        # stuck behind a wedged lane is re-dealt to lanes with room
-        # (producer-side re-striping — see _rebalance_pending) and each
-        # lane grows a victim-cooperative handoff ring its assistant
-        # drains when idle. Off reproduces the PR 5 static striping
-        # exactly. A single-lane pool has nowhere to re-deal to, so it
-        # never pays for any of it (the degenerate pair path below).
-        self._rebalance = bool(rebalance) and lanes > 1
-        self._lanes = [
-            Relic(capacity=capacity, start_awake=start_awake,
-                  name=f"relic-pool-lane{i}", handoff=self._rebalance)
-            for i in range(lanes)
-        ]
-        # The lanes' own bounded-wait probes follow the *pool's* resolved
-        # supervision setting (a kwarg must be able to override the env the
-        # lane constructors just read).
-        for lane in self._lanes:
-            lane._probe_every = _PROBE_EVERY_SPINS if self._supervise else 0
+        # kwarg > RELIC_HEARTBEAT_MS env > default.
+        self._heartbeat_s = (resolve_supervise_config(
+            heartbeat_ms=heartbeat_ms).heartbeat_ms / 1000.0)
+        # Skew resistance: with more than one lane, a burst remainder stuck
+        # behind a wedged lane is re-dealt to lanes with room (producer-side
+        # re-striping — see _rebalance_pending) and each lane has a
+        # victim-cooperative handoff ring its assistant drains when idle.
+        # A single-lane pool has nowhere to re-deal to, so it never pays
+        # for any of it (the degenerate pair path below).
+        self._rebalance = lanes > 1
+        self._lanes = [self._new_lane(f"relic-pool-lane{i}")
+                       for i in range(lanes)]
         self._rr = 0                 # round-robin cursor (next lane to try)
         self._seq = 0                # pool-global submission counter
         # Per-window submission log: _runs[i][k] is the global seq of lane
@@ -326,9 +313,8 @@ class RelicPool:
         self._lost_tasks = 0
         self._retired = RelicStats()
         self._gen = [0] * lanes      # respawn generation per slot (naming)
-        self._supervisor = (
-            LaneSupervisor(lanes, heartbeat_s=self._heartbeat_s)
-            if self._supervise else None)
+        self._supervisor = LaneSupervisor(lanes,
+                                          heartbeat_s=self._heartbeat_s)
         # Hot-path pre-binds: one tuple load per submit instead of chasing
         # lane -> ring / lane -> stats chains per task. Rebuilt whenever
         # the live-lane set changes.
@@ -349,6 +335,15 @@ class RelicPool:
             self._stats0 = self._lane0.stats
             self._submit2 = self._submit2_single
         self.stats = RelicPoolStats(self)
+
+    def _new_lane(self, name: str) -> Relic:
+        """A fresh lane: a Relic, plus its handoff ring when the pool
+        rebalances."""
+        lane = Relic(capacity=self._capacity, start_awake=self._start_awake,
+                     name=name)
+        if self._rebalance:
+            lane._oring = SpscRing(2 * self._capacity)
+        return lane
 
     @property
     def n_lanes(self) -> int:
@@ -433,19 +428,18 @@ class RelicPool:
         busy-wait *sweeping* until some lane accepts. Sweeping — rather
         than committing to one fallback lane — keeps the pool live when a
         lane is wedged behind a long task: backpressure engages only while
-        every ring is full. With rebalancing on, "every ring" includes the
+        every ring is full. In a multi-lane pool "every ring" includes the
         handoff rings: a pool whose primaries are all backed up hands the
         task to the least-loaded lane's handoff ring (its assistant pulls
         from it when its primary goes idle) before resigning to the spin.
 
-        The spin is *bounded* (PR 8): every ``_PROBE_EVERY_SPINS``
+        The spin is *bounded*: every ``_PROBE_EVERY_SPINS``
         no-progress rounds it sweeps lane liveness (``check_lanes``), so a
         pool spinning on rings whose assistants died quarantines them —
         respawn refills the slot with an empty ring the next round, and a
         fully-dead pool raises ``LaneFailedError`` instead of hanging."""
         lanes = self._lanes
         rebalance = self._rebalance
-        supervise = self._supervise
         spins = 0
         pause_every = lanes[0]._spin_pause_every
         while True:
@@ -486,7 +480,7 @@ class RelicPool:
             spins += 1
             if spins % pause_every == 0:
                 time.sleep(0)
-            if supervise and spins % _PROBE_EVERY_SPINS == 0:
+            if spins % _PROBE_EVERY_SPINS == 0:
                 self.check_lanes()
 
     def submit_batch(
@@ -503,7 +497,7 @@ class RelicPool:
         backpressure — every other lane's work is already flowing while
         the producer waits on a full one, and a cross-shard dependency
         (a lane-0 task blocking on a handle from lane 1's shard) can
-        always make progress. With rebalancing on, a remainder the sweep
+        always make progress. In a multi-lane pool a remainder the sweep
         cannot place at all is *re-striped* to lanes that do have room
         (see ``_rebalance_pending``) instead of waiting out its original
         lane.
@@ -593,20 +587,18 @@ class RelicPool:
         lane) and yielding under full-pool backpressure. Partial pushes
         are always pair-aligned: every publication is even-sized, so the
         free-slot count every ``push_many`` sees is even by induction.
-        When a whole sweep makes no progress and rebalancing is on, the
+        When a whole sweep makes no progress in a multi-lane pool, the
         stuck remainders are re-striped to lanes with room before the
         producer resigns itself to spinning.
 
-        Like ``_submit_overflow`` the spin is bounded (PR 8): a periodic
-        liveness sweep quarantines dead lanes mid-burst. A respawned slot
-        offers the remainder a fresh empty ring; with rebalancing on the
-        remainder re-stripes to the survivors; with *both* off a dead
-        slot's remainder can never drain, so the sweep raises
+        Like ``_submit_overflow`` the spin is bounded: a periodic liveness
+        sweep quarantines dead lanes mid-burst. A respawned slot offers the
+        remainder a fresh empty ring, and a remainder stuck on a dead lane
+        re-stripes to the survivors; once no lane is live the sweep raises
         ``LaneFailedError`` (the un-pushed remainder stays unaccounted —
         the same interrupt-safety contract as a KeyboardInterrupt here)."""
         lanes = self._lanes
         rebalance = self._rebalance
-        supervise = self._supervise
         spins = 0
         pause_every = lanes[0]._spin_pause_every
         while pending:
@@ -638,11 +630,9 @@ class RelicPool:
                 spins += 1
                 if spins % pause_every == 0:
                     time.sleep(0)
-                if supervise and spins % _PROBE_EVERY_SPINS == 0 \
-                        and self.check_lanes():
-                    if not self._respawn and not rebalance and any(
-                            e[0] not in self._live for e in pending):
-                        raise LaneFailedError(tuple(self._failures))
+                if (spins % _PROBE_EVERY_SPINS == 0 and self.check_lanes()
+                        and not self._live):
+                    self._raise_pool_dead()
 
     def _rebalance_pending(self, flat: list, pending: List[list],
                            seq0: int) -> bool:
@@ -683,8 +673,6 @@ class RelicPool:
                     moved = True
                     continue
                 oring = lane._oring
-                if oring is None:
-                    continue
                 room = oring.free_slots() // 2
                 if room <= 0:
                     continue
@@ -712,7 +700,7 @@ class RelicPool:
         (they remain counted in ``stats.task_errors``) — the same
         later-failures-only-bump rule the pair applies within one lane.
 
-        Lane deaths outrank task errors (PR 8): a barrier that finds a
+        Lane deaths outrank task errors: a barrier that finds a
         dead assistant (its bounded-wait probe raises ``RelicDeadError``)
         quarantines the lane — respawning into the slot when enabled —
         and ``wait()`` raises :class:`LaneFailedError` carrying every
@@ -738,7 +726,7 @@ class RelicPool:
         for i in range(self._n):
             # base + len(runs) == tasks ever pushed to that ring: the next
             # window's local indexes continue from there. (Not the lane's
-            # ``submitted`` — with rebalancing that counter spans both
+            # ``submitted`` — in a multi-lane pool that counter spans both
             # rings, while each log is per-ring.)
             self._base[i] += len(self._runs[i])
             self._runs[i].clear()
@@ -761,7 +749,7 @@ class RelicPool:
         if errors:
             raise errors[0][1]
 
-    # ------------------------------------------------- lane supervision (PR 8)
+    # ------------------------------------------------------- lane supervision
 
     def _rebuild_hot(self) -> None:
         """Regenerate the submit pre-binds from the live-lane set (called
@@ -828,19 +816,13 @@ class RelicPool:
             r.assistant_empty_spins += s.assistant_empty_spins
             r.parks += s.parks
             self._gen[li] += 1
-            fresh = Relic(capacity=self._capacity,
-                          start_awake=self._start_awake,
-                          name=f"relic-pool-lane{li}-r{self._gen[li]}",
-                          handoff=self._rebalance)
-            fresh._probe_every = (_PROBE_EVERY_SPINS if self._supervise
-                                  else 0)
+            fresh = self._new_lane(f"relic-pool-lane{li}-r{self._gen[li]}")
             self._lanes[li] = fresh
             self._runs[li] = []
             self._base[li] = 0
             self._oruns[li] = []
             self._obase[li] = 0
-            if self._supervisor is not None:
-                self._supervisor.reset_lane(li)
+            self._supervisor.reset_lane(li)
             if self._started:
                 fresh.start()
             self._live.append(li)
@@ -855,25 +837,21 @@ class RelicPool:
         to call often (the supervisor samples once per heartbeat period).
         Returns the *new* failures; they also stay queued for the next
         ``wait()`` unless drained with ``take_lane_failures``."""
-        if not self._supervise:
-            return []
         new: List[LaneFailure] = []
         for li in list(self._live):
             lane = self._lanes[li]
             if not lane.is_alive():
                 new.append(self._quarantine_lane(li, lane))
-        sup = self._supervisor
-        if sup is not None:
-            completed: List[int] = []
-            outstanding: List[int] = []
-            for li, lane in enumerate(self._lanes):
-                done = lane._completed
-                completed.append(done)
-                # A quarantined slot reads as idle, not stalled: nothing
-                # is outstanding that supervision could still save.
-                outstanding.append(
-                    (lane.stats.submitted - done) if li in self._live else 0)
-            sup.observe(completed, outstanding)
+        completed: List[int] = []
+        outstanding: List[int] = []
+        for li, lane in enumerate(self._lanes):
+            done = lane._completed
+            completed.append(done)
+            # A quarantined slot reads as idle, not stalled: nothing is
+            # outstanding that supervision could still save.
+            outstanding.append(
+                (lane.stats.submitted - done) if li in self._live else 0)
+        self._supervisor.observe(completed, outstanding)
         return new
 
     def take_lane_failures(self) -> Tuple[LaneFailure, ...]:
@@ -906,12 +884,11 @@ class RelicPool:
     def stalled_lanes(self) -> List[int]:
         """Advisory: slots with outstanding work and no completion
         progress for ~2 heartbeat periods (see ``LaneSupervisor``)."""
-        return [] if self._supervisor is None else self._supervisor.stalled()
+        return self._supervisor.stalled()
 
     def straggler_lanes(self) -> List[int]:
         """Advisory: slots persistently slower than their peers."""
-        return ([] if self._supervisor is None
-                else self._supervisor.stragglers())
+        return self._supervisor.stragglers()
 
     @property
     def live_lanes(self) -> Tuple[int, ...]:

@@ -72,10 +72,10 @@ from dataclasses import dataclass, field
 from typing import (Any, Callable, Dict, Iterable, List, Optional, Protocol,
                     Tuple, runtime_checkable)
 
-from repro.core.relic import (Relic, RelicUsageError,
-                              resolve_spin_pause_every)
+from repro.core.relic import Relic, RelicUsageError
 from repro.core.relic_pool import RelicPool
 from repro.core.spsc import DEFAULT_CAPACITY
+from repro.runtime.config import resolve_spin_pause_every
 
 __all__ = [
     "Scheduler",
@@ -413,24 +413,18 @@ class RelicPoolScheduler(_RelicAdapterBase):
     Registered as ``relic-pool`` (``lanes=N`` keyword, default 2) with
     convenience names ``relic2`` and ``relic4``.
 
-    ``rebalance`` (default on, multi-lane only) enables the pool's skew
-    resistance: producer-side re-striping of stuck burst remainders plus
-    per-lane victim-cooperative handoff rings — dynamic load balancing
-    that keeps every ring strictly SPSC (see ``repro.core.relic_pool``
-    and docs/schedulers.md). ``rebalance=False`` is the PR 5 static
-    striping, kept addressable for A/B measurement (the ``skew``
-    benchmark section runs both)."""
+    A multi-lane pool is skew resistant: producer-side re-striping of
+    stuck burst remainders plus per-lane victim-cooperative handoff rings
+    — dynamic load balancing that keeps every ring strictly SPSC (see
+    ``repro.core.relic_pool`` and docs/schedulers.md)."""
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY, lanes: int = 2,
-                 start_awake: bool = True, rebalance: bool = True,
-                 respawn: bool = False, supervise: Optional[bool] = None,
+                 start_awake: bool = True, respawn: bool = False,
                  heartbeat_ms: Optional[float] = None):
         super().__init__()
         self._rt = self._pool = RelicPool(lanes=lanes, capacity=capacity,
                                           start_awake=start_awake,
-                                          rebalance=rebalance,
                                           respawn=respawn,
-                                          supervise=supervise,
                                           heartbeat_ms=heartbeat_ms)
         # Hot-path pre-bind: the pool's no-checks striped push.
         self._submit2 = self._pool._submit2
@@ -473,7 +467,7 @@ class RelicPoolScheduler(_RelicAdapterBase):
             fn = functools.partial(fn, **kwargs)
         self._submit2(fn, args)
 
-    # Lane-supervision pass-throughs (PR 8) for fire-and-observe consumers
+    # Lane-supervision pass-throughs for fire-and-observe consumers
     # (the serve loop never calls wait(), so it reads lane health here).
     def poll_lane_failures(self):
         """One supervision sweep + drain: quarantine newly dead lanes

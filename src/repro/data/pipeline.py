@@ -4,7 +4,9 @@ The host-side instance of the paper's pattern (docs/schedulers.md): the **assist
 thread produces** batches (synthetic generation / memmap reads / host->device
 transfer release the GIL) while the **main thread consumes** them in the
 train loop. `wake_up_hint()` is issued when the loop starts, `sleep_hint()`
-between epochs/evals — the paper's explicit control points.
+between epochs/evals — the paper's explicit control points. The batches
+flow through a ``repro.stream.Pipeline``, so every wait on them is
+bounded by the same liveness probes as the rest of the runtime.
 
 Determinism/restart: batch `i` is a pure function of (seed, i, shard), so
 resuming from step `i` after a failure replays the exact stream; no iterator
@@ -98,13 +100,11 @@ class PrefetchPipeline:
     *every* substrate — the linear pipeline is FIFO end-to-end, so no
     index stash is needed either.
 
-    Supervision (PR 8 discipline, closing the PR 8 gap in this file):
-    every wait — consumer pops in ``next_batch()``, producer pushes on a
-    full ring — is bounded, probing the neighbouring thread's liveness
-    every ``_PROBE_EVERY_SPINS`` spins and raising
+    Supervision: every wait — consumer pops in ``next_batch()``, producer
+    pushes on a full ring — is bounded, probing the neighbouring thread's
+    liveness every ``_PROBE_EVERY_SPINS`` spins and raising
     :class:`repro.core.relic.RelicDeadError` with fed/drained diagnostics
-    instead of spinning on a stream that can never advance
-    (``RELIC_SUPERVISE=0`` opts out, same switch as the substrate).
+    instead of spinning on a stream that can never advance.
 
     Failures stay in-stream: a batch whose production (or transform)
     raised arrives as a marker and ``next_batch()`` raises

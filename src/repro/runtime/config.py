@@ -6,11 +6,8 @@ place, with one discipline: knobs are **re-read per instance** (a new
 never frozen at import time, so a CI container and a local SMT host can run
 the same code path by exporting a variable instead of editing a module.
 
-Two families:
-
 ``RELIC_SPIN_PAUSE_EVERY``
-    The spin/yield cadence for the busy-wait loops (paper §VI-B). Moved here
-    from ``repro.core.relic`` (which still re-exports it for back-compat).
+    The spin/yield cadence for the busy-wait loops (paper §VI-B).
 
 ``RELIC_SERVE_*``
     Knobs for the ``repro.serve`` request-serving subsystem:
@@ -27,25 +24,22 @@ Two families:
       idempotent-marked request whose task erred or whose lane died
       (default 2; ``0`` disables retry).
 
-``RELIC_SUPERVISE`` / ``RELIC_HEARTBEAT_MS``
-    The liveness/supervision knobs (docs/robustness.md):
-
-    - ``RELIC_SUPERVISE``: ``1`` (default) arms the bounded-wait liveness
-      probes (every producer spin loop periodically checks
-      ``assistant.is_alive()`` and raises ``RelicDeadError`` instead of
-      hanging) and the pool's ``LaneSupervisor``; ``0`` restores the
-      pre-supervision behaviour exactly (unbounded spins).
-    - ``RELIC_HEARTBEAT_MS``: cadence (milliseconds, default 100) at which
-      the ``LaneSupervisor`` samples per-lane progress heartbeats into
-      ``HeartbeatTracker``/``StragglerMonitor``; a lane with outstanding
-      work and no progress for one full period is flagged as stalled.
+``RELIC_HEARTBEAT_MS``
+    The cadence (milliseconds, default 100) of lane supervision
+    (docs/robustness.md): the ``LaneSupervisor`` samples per-lane progress
+    heartbeats into ``HeartbeatTracker``/``StragglerMonitor`` at this
+    period, and a lane with outstanding work and no progress for one full
+    period is flagged as stalled. Supervision itself is always on: every
+    producer spin loop checks ``assistant.is_alive()`` every
+    ``_PROBE_EVERY_SPINS`` rounds and raises ``RelicDeadError`` instead of
+    hanging.
 
 ``RELIC_CKPT_CHECKSUM``
     Crash-consistency knob for ``repro.checkpoint``: ``1`` (default) makes
     ``CheckpointManager`` record a CRC32 per entry in the manifest and
     verify it on restore (falling back to the next-latest valid step on a
-    mismatch); ``0`` skips both (the pre-PR-10 format, still restorable —
-    entries without a checksum are simply not verified).
+    mismatch); ``0`` skips both (entries without a checksum are simply
+    not verified).
 
 ``JAX_COMPILATION_CACHE_DIR``
     Where JAX keeps its persistent compilation cache. JAX reads the
@@ -54,9 +48,8 @@ Two families:
     when it is unset.
 
 ``resolve_serve_config()`` / ``resolve_supervise_config()`` /
-``resolve_checkpoint_config()`` return frozen snapshots recorded in BENCH
-meta alongside the spin cadence, so a recorded run's knob state is
-reproducible.
+``resolve_checkpoint_config()`` return frozen snapshots of one instance's
+knob state.
 """
 
 from __future__ import annotations
@@ -220,11 +213,9 @@ _FALSY = ("0", "false", "no", "off")
 
 @dataclass(frozen=True)
 class SuperviseConfig:
-    """Resolved ``RELIC_SUPERVISE``/``RELIC_HEARTBEAT_MS`` knob snapshot
-    for one runtime instance (a ``Relic``, a ``RelicPool``, a
-    ``ServeScheduler``)."""
+    """Resolved ``RELIC_HEARTBEAT_MS`` knob snapshot for one runtime
+    instance (a ``RelicPool``, a ``ServeScheduler``)."""
 
-    supervise: bool = True
     heartbeat_ms: float = 100.0
 
     def asdict(self) -> dict:
@@ -233,29 +224,15 @@ class SuperviseConfig:
 
 def resolve_supervise_config(
     *,
-    supervise: Optional[bool] = None,
     heartbeat_ms: Optional[float] = None,
 ) -> SuperviseConfig:
-    """Resolve the liveness-supervision knobs for a *new* runtime instance.
+    """Resolve the supervision cadence for a *new* runtime instance.
 
-    Same discipline as ``resolve_serve_config``: explicit keyword arguments
-    win over the environment, the environment wins over the defaults,
-    invalid values raise ``ValueError``, and the result is re-read per
-    instance (never frozen at import).
+    Same discipline as ``resolve_serve_config``: an explicit keyword
+    argument wins over the environment, the environment wins over the
+    default, invalid values raise ``ValueError``, and the result is re-read
+    per instance (never frozen at import).
     """
-    if supervise is None:
-        raw = os.environ.get("RELIC_SUPERVISE")
-        if raw is None or raw == "":
-            supervise = True
-        elif raw.strip().lower() in _TRUTHY:
-            supervise = True
-        elif raw.strip().lower() in _FALSY:
-            supervise = False
-        else:
-            raise ValueError(
-                f"RELIC_SUPERVISE must be one of {_TRUTHY + _FALSY}, "
-                f"got {raw!r}")
-
     if heartbeat_ms is None:
         raw = os.environ.get("RELIC_HEARTBEAT_MS")
         if raw:
@@ -272,8 +249,7 @@ def resolve_supervise_config(
             "RELIC_HEARTBEAT_MS must be a positive number, "
             f"got {heartbeat_ms!r}")
 
-    return SuperviseConfig(supervise=bool(supervise),
-                           heartbeat_ms=float(heartbeat_ms))
+    return SuperviseConfig(heartbeat_ms=float(heartbeat_ms))
 
 
 @dataclass(frozen=True)

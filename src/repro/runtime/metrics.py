@@ -3,8 +3,7 @@
 Home of the nearest-rank percentile helpers, :class:`LatencySeries` and
 :class:`Gauge`, moved here from ``repro.serve.metrics`` (PR 9) so the
 streaming executor's per-stage latency/occupancy rows reuse them instead of
-duplicating — the same move pattern as ``resolve_spin_pause_every``
-migrating into ``repro.runtime.config`` (PR 7). ``repro.serve.metrics``
+duplicating. ``repro.serve.metrics``
 re-exports every name, identity-pinned by ``tests/test_runtime_metrics.py``,
 so existing imports keep working unchanged.
 

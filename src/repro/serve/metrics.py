@@ -4,9 +4,7 @@ The generic primitives — ``nearest_rank``/``percentiles``, ``LatencySeries``
 and ``Gauge`` — live in :mod:`repro.runtime.metrics` (moved there in PR 9 so
 the streaming executor's stage-latency/occupancy rows share them); this
 module re-exports them unchanged, identity-pinned by
-``tests/test_runtime_metrics.py`` — the same compatibility pattern as
-``resolve_spin_pause_every`` re-exported from ``repro.core.relic`` after its
-move into ``repro.runtime.config`` (PR 7). Existing
+``tests/test_runtime_metrics.py``. Existing
 ``from repro.serve.metrics import ...`` call sites keep working.
 
 What stays here is the serving-specific aggregate: ``ServeMetrics``.

@@ -1,9 +1,9 @@
 """Continuous-batching scheduler loop on the Relic tasking substrate.
 
 The serving shape of the paper's runtime: a single **scheduler loop thread**
-owns a ``RelicPool``-backed scheduler (creates it, submits to it, closes it
-— the pool's owner-thread contract) and runs the admit/dispatch/finalize
-cycle:
+owns a ``relic-pool`` scheduler built with ``respawn=True`` (creates it,
+submits to it, closes it — the pool's owner-thread contract) and runs the
+admit/dispatch/finalize cycle:
 
 1. **finalize** — observe ``Response.done()`` on in-flight requests (the
    assistant lanes publish via the lazy-Event flag; the loop never blocks
@@ -12,10 +12,11 @@ cycle:
    (``RELIC_SERVE_BATCH_MAX`` minus in-flight), stamp ``admit_t``, shed
    requests whose deadline already passed (surfaced as
    ``deadline_exceeded``, never silently dropped), and submit the rest to
-   the pool lanes via ``submit_many`` (lane striping + rebalance are the
-   existing RelicPool machinery). A group (``ClientHandle.submit_group``)
-   is one ring entry and one lane task: it takes one entry of the budget
-   and is admitted, shed or cancelled whole, all its members in flight;
+   the pool lanes via ``submit_many`` (lane striping and, with more than
+   one lane, rebalancing are the RelicPool's own machinery). A group
+   (``ClientHandle.submit_group``) is one ring entry and one lane task: it
+   takes one entry of the budget and is admitted, shed or cancelled whole,
+   all its members in flight;
 3. **park** — when idle long enough, publish a parked flag and sleep on an
    Event that ``ClientHandle.submit`` sets only when it observes the flag —
    the same advisory-hint philosophy as ``Relic.sleep_hint`` /
@@ -32,7 +33,7 @@ Task errors are contained in ``_execute`` (the Response carries them);
 a failed request never becomes a failed pool task, so the pool's
 first-error-wins machinery stays quiet and serving continues.
 
-**Retry & lane supervision (PR 8).** Requests submitted with
+**Retry & lane supervision.** Requests submitted with
 ``idempotent=True`` are retried on failure under a deterministic
 ``RetryPolicy`` (bounded attempts, exponential backoff, seeded jitter): a
 retry-eligible failure is never published — ``_execute`` marks the response
@@ -43,10 +44,9 @@ when one died, recovery is *quiesce-then-diff*: stop admitting, let the
 surviving lanes drain (``in_flight_estimate() → 0``, bounded), and the
 in-flight responses that are neither finished nor retry-marked are exactly
 the tasks the dead ring lost — idempotent ones are re-admitted, the rest
-finish ``STATUS_ERROR`` carrying the ``LaneFailedError``. The pool itself
-is constructed with ``respawn=True`` so capacity recovers. With
-``RELIC_SUPERVISE=0`` all of this is off and the loop is byte-identical to
-the PR 7 cycle.
+finish ``STATUS_ERROR`` carrying the ``LaneFailedError``. The pool's
+``respawn=True`` puts a fresh lane in the dead one's slot, so capacity
+recovers.
 """
 
 from __future__ import annotations
@@ -111,20 +111,17 @@ class ServeScheduler:
         lanes: int = 2,
         capacity: Optional[int] = None,
         config: Optional[ServeConfig] = None,
-        scheduler: str = "relic-pool",
         retry_policy: Optional[RetryPolicy] = None,
     ) -> None:
         if lanes < 0:
             raise ValueError(f"lanes must be >= 0, got {lanes}")
         self.lanes = lanes
         self._capacity = capacity
-        self._scheduler_name = scheduler
         self.config = config or resolve_serve_config()
         self.retry_policy = retry_policy or RetryPolicy.from_config(
             self.config)
-        sup = resolve_supervise_config()
-        self._supervise = sup.supervise
-        self._sweep_period_s = sup.heartbeat_ms / 1000.0
+        self._sweep_period_s = (
+            resolve_supervise_config().heartbeat_ms / 1000.0)
         self.metrics = ServeMetrics()
         self._wake_event = threading.Event()
         self._parked = False
@@ -198,7 +195,7 @@ class ServeScheduler:
         snap["in_flight"] = len(self._in_flight)
         snap["pending"] = self.ingest.pending()
         snap["config"] = self.config.asdict()
-        # Robustness telemetry (PR 8): retry volume, lane failures observed
+        # Robustness telemetry: retry volume, lane failures observed
         # and requests they lost, plus the latest supervision sweep's lane
         # health (stalled/straggler lane indexes — cached by the loop
         # thread so foreign readers never touch the supervisor's state).
@@ -207,7 +204,6 @@ class ServeScheduler:
         snap["lost_requests"] = self._lost_requests
         snap["stalled_lanes"] = list(self._lane_health["stalled"])
         snap["straggler_lanes"] = list(self._lane_health["stragglers"])
-        snap["supervise"] = self._supervise
         return snap
 
     def _loop_alive(self) -> bool:
@@ -302,12 +298,11 @@ class ServeScheduler:
 
     # -- scheduler loop ----------------------------------------------------
 
-    def _dispatch(self, sched: Any, submits: List[tuple],
-                  supervised: bool) -> bool:
-        """Push a batch at the substrate. Returns True if the substrate
-        reported lane death mid-dispatch (recoverable when supervised: the
-        quiesce-then-diff sweep classifies every in-flight response,
-        including any of this batch that never reached a ring)."""
+    def _dispatch(self, sched: Any, submits: List[tuple]) -> bool:
+        """Push a batch at the pool. Returns True if the pool reported lane
+        death mid-dispatch (recoverable: the quiesce-then-diff sweep
+        classifies every in-flight response, including any of this batch
+        that never reached a ring)."""
         if sched is None:
             for fn, args, _ in submits:
                 fn(*args)
@@ -315,8 +310,6 @@ class ServeScheduler:
         try:
             sched.submit_many(submits)
         except RelicDeadError:
-            if not supervised:
-                raise
             return True
         return False
 
@@ -373,14 +366,7 @@ class ServeScheduler:
                 kwargs: Dict[str, Any] = {"lanes": self.lanes}
                 if self._capacity is not None:
                     kwargs["capacity"] = self._capacity
-                try:
-                    # Pool-family substrates grow capacity back after a
-                    # lane death; substrates without the kwarg (the plain
-                    # pair, thread pools) reject it and are built as-is.
-                    sched = make_scheduler(
-                        self._scheduler_name, respawn=True, **kwargs)
-                except TypeError:
-                    sched = make_scheduler(self._scheduler_name, **kwargs)
+                sched = make_scheduler("relic-pool", respawn=True, **kwargs)
                 sched.start()
                 self._sched = sched
         except BaseException as exc:  # noqa: BLE001 - surface via start()
@@ -396,9 +382,7 @@ class ServeScheduler:
         pause_every = resolve_spin_pause_every()
         policy = self.retry_policy
         retry_queue: List[Response] = []
-        supervised = (self._supervise and sched is not None
-                      and hasattr(sched, "poll_lane_failures"))
-        next_sweep_t = now() + self._sweep_period_s if supervised else 0.0
+        next_sweep_t = now() + self._sweep_period_s
         idle_spins = 0
         try:
             while True:
@@ -461,8 +445,7 @@ class ServeScheduler:
                             resp.first_result_t = None
                             in_flight[req.rid] = resp
                             submits.append((self._execute, (resp,), {}))
-                        if submits and self._dispatch(
-                                sched, submits, supervised):
+                        if submits and self._dispatch(sched, submits):
                             next_sweep_t = 0.0
 
                 # 2b. admit: fill the sliding window mid-stream.
@@ -492,15 +475,14 @@ class ServeScheduler:
                                 member.attempts += 1
                                 in_flight[member.request.rid] = member
                             submits.append((self._execute, (resp,), {}))
-                        if submits and self._dispatch(
-                                sched, submits, supervised):
+                        if submits and self._dispatch(sched, submits):
                             next_sweep_t = 0.0
                         metrics.queue_depth.observe(ingest.pending())
                         metrics.batch_occupancy.observe(len(in_flight))
 
                 # 2c. supervise: poll lane liveness/health on the heartbeat
                 # cadence; dead lanes trigger quiesce-then-diff recovery.
-                if supervised and now() >= next_sweep_t:
+                if sched is not None and now() >= next_sweep_t:
                     next_sweep_t = now() + self._sweep_period_s
                     failures = sched.poll_lane_failures()
                     self._lane_health = {

@@ -58,7 +58,7 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Any, Callable, List, Optional, Tuple
 
-from repro.core.relic import RelicDeadError
+from repro.core.relic import _PROBE_EVERY_SPINS, RelicDeadError
 from repro.core.spsc import DEFAULT_CAPACITY, SpscRing
 from repro.stream.stage import (STOP, Stage, StageFailedError, StreamFailure,
                                 StreamUsageError, _always_alive)
@@ -110,7 +110,6 @@ class _Emitter(Stage):
         pop = self._in.pop
         redeal = farm._redeal
         pause_every = self._pause_every
-        probe_every = self._probe_every
         rr = 0
         seq = 0
         spins = 0
@@ -131,7 +130,7 @@ class _Emitter(Stage):
                     time.sleep(200e-6)    # parked idle (see Stage.sleep_hint)
                 elif spins % pause_every == 0:
                     time.sleep(0)
-                if not (probe_every and spins % probe_every == 0):
+                if spins % _PROBE_EVERY_SPINS:
                     continue
                 if self._upstream_alive():
                     continue
@@ -158,7 +157,6 @@ class _Emitter(Stage):
         farm = self._farm
         n = len(farm._workers)
         pause_every = self._pause_every
-        probe_every = self._probe_every
         wait_spins = 0
         while True:
             for k in range(n):
@@ -174,7 +172,7 @@ class _Emitter(Stage):
             wait_spins += 1
             if wait_spins % pause_every == 0:
                 time.sleep(0)
-            if probe_every and wait_spins % probe_every == 0:
+            if wait_spins % _PROBE_EVERY_SPINS == 0:
                 if not any(w.alive() for w in farm._workers) and not (
                         farm._respawn and self._accepting
                         and farm._collector.alive()):
@@ -223,7 +221,7 @@ class _Emitter(Stage):
             spins += 1
             if spins % self._pause_every == 0:
                 time.sleep(0)
-            if (self._probe_every and spins % self._probe_every == 0
+            if (spins % _PROBE_EVERY_SPINS == 0
                     and not farm._workers[i].alive()):
                 # Dead worker: the collector's probe accounts it (a
                 # quarantined dead slot at this point is terminally
@@ -248,7 +246,6 @@ class _Collector(Stage):
         n = len(outs)
         ordered = farm.ordered
         respawn = farm._respawn
-        probe_every = self._probe_every
         pause_every = self._pause_every
         stops = [False] * n
         remaining = n
@@ -443,7 +440,7 @@ class _Collector(Stage):
                 time.sleep(200e-6)        # parked idle (see Stage.sleep_hint)
             elif spins % pause_every == 0:
                 time.sleep(0)
-            if not (probe_every and spins % probe_every == 0):
+            if spins % _PROBE_EVERY_SPINS:
                 continue
             for i in range(n):
                 if stops[i] or workers[i].alive():

@@ -25,10 +25,9 @@ Composition rules (docs/streaming.md walks through the why):
   unwraps markers into :class:`StreamError`; ``get_raw()`` hands them
   back for callers that do their own accounting (PrefetchPipeline's
   error contract, CheckpointManager's wait()).
-* Every driver-side wait is bounded by the PR 8 supervision discipline:
-  liveness probe every ``_PROBE_EVERY_SPINS`` spins, ``RelicDeadError``
-  with fed/drained diagnostics when a stage died, ``RELIC_SUPERVISE=0``
-  opt-out.
+* Every driver-side wait is bounded: a liveness probe every
+  ``_PROBE_EVERY_SPINS`` spins raises ``RelicDeadError`` with fed/drained
+  diagnostics when a stage died.
 """
 
 from __future__ import annotations
@@ -40,8 +39,7 @@ from typing import Any, Callable, Iterable, List, Optional, Sequence, Union
 from repro.core.relic import _PROBE_EVERY_SPINS, RelicDeadError
 from repro.core.schedulers import Scheduler
 from repro.core.spsc import DEFAULT_CAPACITY, SpscRing
-from repro.runtime.config import (resolve_spin_pause_every,
-                                  resolve_supervise_config)
+from repro.runtime.config import resolve_spin_pause_every
 from repro.stream.stage import (STOP, Stage, StreamError, StreamFailure,
                                 StreamUsageError)
 
@@ -124,10 +122,8 @@ class Pipeline:
         self._got = 0      # items got
         self._started = False
         self._closed = False
-        self._probe_every = (_PROBE_EVERY_SPINS
-                             if resolve_supervise_config().supervise else 0)
         self._pause_every = resolve_spin_pause_every()
-        # Advisory progress supervision (PR 8's LaneSupervisor lifted to
+        # Advisory progress supervision (the pool's LaneSupervisor lifted to
         # the stage stratum): one "lane" per stage, fed this pipeline's
         # fed/drained counters on every driver-side bounded-wait probe
         # (and on explicit check_stages() calls). Strictly advisory — the
@@ -230,7 +226,7 @@ class Pipeline:
                 spins += 1
                 if spins % self._pause_every == 0:
                     time.sleep(0)
-                if (self._probe_every and spins % self._probe_every == 0
+                if (spins % _PROBE_EVERY_SPINS == 0
                         and not first.alive()):
                     break
             # Drain the sink until STOP comes out the far end (discarding
@@ -246,7 +242,7 @@ class Pipeline:
                 spins += 1
                 if spins % self._pause_every == 0:
                     time.sleep(0)
-                if (self._probe_every and spins % self._probe_every == 0
+                if (spins % _PROBE_EVERY_SPINS == 0
                         and not last.alive()):
                     if self._sink.pop() is None:  # racing final publication
                         break
@@ -308,7 +304,7 @@ class Pipeline:
             spins += 1
             if spins % self._pause_every == 0:
                 time.sleep(0)
-            if self._probe_every and spins % self._probe_every == 0:
+            if spins % _PROBE_EVERY_SPINS == 0:
                 self.check_stages()
                 if not first.alive():
                     raise self._dead(first)
@@ -351,7 +347,7 @@ class Pipeline:
             spins += 1
             if spins % self._pause_every == 0:
                 time.sleep(0)
-            if self._probe_every and spins % self._probe_every == 0:
+            if spins % _PROBE_EVERY_SPINS == 0:
                 self.check_stages()
                 if not last.alive():
                     item = pop()  # final re-pop: published right before death
@@ -422,7 +418,7 @@ class Pipeline:
             spins += 1
             if spins % self._pause_every == 0:
                 time.sleep(0)
-            if self._probe_every and spins % self._probe_every == 0:
+            if spins % _PROBE_EVERY_SPINS == 0:
                 self.check_stages()
                 if not last.alive():
                     item = pop()

@@ -19,13 +19,12 @@ fast paths of :class:`repro.core.spsc.SpscRing` stay valid, and no lock or
 MPMC queue appears anywhere on the item path (pinned by
 ``tests/test_stream.py``).
 
-Waiting discipline (PR 8): every spin loop here is *bounded*. A popping
+Waiting discipline: every spin loop here is *bounded*. A popping
 stage probes its upstream's liveness every ``_PROBE_EVERY_SPINS`` spins
 and raises :class:`repro.core.relic.RelicDeadError` (with fed/drained
 diagnostics) instead of spinning forever on a ring nothing will ever fill;
 a pushing stage symmetrically probes its downstream before waiting on a
-ring nothing will ever drain. ``RELIC_SUPERVISE=0`` opts out, same switch
-as the substrate.
+ring nothing will ever drain.
 
 In-band control flow:
 
@@ -53,8 +52,7 @@ from typing import Any, Callable, Iterable, List, Optional, Union
 from repro.core.relic import _PROBE_EVERY_SPINS, RelicDeadError
 from repro.core.schedulers import Scheduler, make_scheduler
 from repro.core.spsc import DEFAULT_CAPACITY, SpscRing
-from repro.runtime.config import (resolve_spin_pause_every,
-                                  resolve_supervise_config)
+from repro.runtime.config import resolve_spin_pause_every
 from repro.runtime.metrics import Gauge, LatencySeries
 from repro.tasks.api import TaskScope
 
@@ -204,8 +202,6 @@ class Stage:
         # parked idle loop sleeps in ms ticks so a stopped-but-alive
         # network doesn't tax the host (sleep_hint's whole point).
         self._parked = False
-        self._probe_every = (_PROBE_EVERY_SPINS
-                             if resolve_supervise_config().supervise else 0)
         self._pause_every = resolve_spin_pause_every()
         # Opt-in chaos hook (None in production): consulted once per popped
         # data item; a fired switch kills the loop with the item popped but
@@ -328,15 +324,14 @@ class Stage:
     def _run_loop(self) -> None:
         fn = self.fn
         pop = self._in.pop
-        probe_every = self._probe_every
         pause_every = self._pause_every
         record = self.record
         spins = 0
         while True:
             item = pop()
             if item is None:
-                # Bounded wait (PR 8 discipline): yield on the pause
-                # cadence; every probe_every spins check the producer is
+                # Bounded wait: yield on the pause cadence; every
+                # _PROBE_EVERY_SPINS spins check the producer is
                 # still there, re-popping once after a failed probe so an
                 # item (or STOP) published right before death is drained.
                 # A parked loop trades wake latency for idle CPU instead.
@@ -345,7 +340,7 @@ class Stage:
                     time.sleep(200e-6)
                 elif spins % pause_every == 0:
                     time.sleep(0)
-                if not (probe_every and spins % probe_every == 0):
+                if spins % _PROBE_EVERY_SPINS:
                     continue
                 if self._upstream_alive():
                     continue
@@ -380,14 +375,13 @@ class Stage:
         """Bounded-wait push into the output ring (backpressure point)."""
         if self._out.push(item):
             return
-        probe_every = self._probe_every
         pause_every = self._pause_every
         spins = 0
         while True:
             spins += 1
             if spins % pause_every == 0:
                 time.sleep(0)
-            if (probe_every and spins % probe_every == 0
+            if (spins % _PROBE_EVERY_SPINS == 0
                     and not self._downstream_alive()):
                 raise self._dead_downstream()
             if self._out.push(item):

@@ -16,6 +16,7 @@ switches) — no sleeps-as-synchronization, no flaky timing assumptions
 beyond "a live lane eventually drains its ring".
 """
 
+import threading
 import time
 
 import pytest
@@ -177,15 +178,6 @@ def test_relic_submit_slow_path_raises_on_dead_assistant():
             r.submit(time.sleep, 0)
 
 
-def test_relic_supervise_off_disables_probes():
-    r = Relic(capacity=4)
-    assert r._probe_every > 0          # default: supervised
-    p = RelicPool(lanes=2, supervise=False)
-    assert all(lane._probe_every == 0 for lane in p._lanes)
-    assert p.check_lanes() == []       # no-op without supervision
-    p.shutdown()
-
-
 # ---------------------------------------------------------------- the pool
 
 
@@ -254,6 +246,38 @@ def test_pool_fully_dead_keeps_raising():
         for i in range(1000):
             pool.submit(time.sleep, 0)
     pool.shutdown()
+
+
+def test_pool_burst_raises_once_every_lane_died_mid_sweep():
+    """A burst whose remainders are stuck on rings whose assistants all
+    died (respawn off) has no live lane to re-stripe to: the sweep's
+    liveness probe quarantines them and raises instead of spinning. The
+    producer runs on a thread of its own so a hang fails the test."""
+    outcome = []
+
+    def producer():
+        pool = RelicPool(lanes=2, capacity=2, start_awake=True).start()
+        switches = [KillSwitch(after_bursts=0).arm(lane)
+                    for lane in pool._lanes]
+        pool.submit(time.sleep, 0)
+        pool.submit(time.sleep, 0)
+        deadline = time.time() + 5
+        while (any(lane._assistant.is_alive() for lane in pool._lanes)
+               and time.time() < deadline):
+            time.sleep(0.001)
+        assert all(ks.fired for ks in switches)
+        try:
+            pool.submit_batch([(time.sleep, (0,), {})] * 20)
+        except LaneFailedError as e:
+            outcome.append((e, pool.live_lanes))
+        pool.shutdown()
+
+    t = threading.Thread(target=producer, daemon=True)
+    t.start()
+    t.join(10)
+    assert not t.is_alive(), "the sweep spun on a fully dead pool"
+    assert len(outcome) == 1
+    assert outcome[0][1] == ()
 
 
 def test_pool_scheduler_adapter_surfaces_lane_failures():
@@ -436,5 +460,5 @@ def test_serve_stats_surface_robustness_fields():
     with ServeScheduler(lanes=2) as server:
         snap = server.stats()
     for key in ("retries", "lane_failures", "lost_requests",
-                "stalled_lanes", "straggler_lanes", "supervise"):
+                "stalled_lanes", "straggler_lanes"):
         assert key in snap

@@ -350,14 +350,3 @@ def test_no_lock_no_queue_in_recovery_path():
     src = inspect.getsource(farm_mod)
     assert "Lock(" not in src
     assert "queue.Queue" not in src
-
-
-def test_supervise_off_reproduces_unbounded_loops(monkeypatch):
-    """RELIC_SUPERVISE=0 must still produce probe-free stages (the
-    pre-supervision loops) after the recovery rework."""
-    monkeypatch.setenv("RELIC_SUPERVISE", "0")
-    f = Farm(lambda x: x, workers=2)
-    assert f._emitter._probe_every == 0
-    assert f._collector._probe_every == 0
-    with Pipeline([f]) as pipe:
-        assert pipe.run(range(50)) == list(range(50))

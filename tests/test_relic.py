@@ -519,3 +519,77 @@ def test_relic_submit_accounts_only_after_the_push_lands(monkeypatch):
         gate.set()
         rt.wait()                          # terminates: no phantom task
         assert rt.stats.completed == 2
+
+
+# ------------------------------------------------- the one assistant loop
+
+@pytest.mark.parametrize("handoff_ring, fail_on", [
+    (False, "primary"),
+    (True, "primary"),
+    (True, "handoff"),
+], ids=["pair", "lane-primary-error", "lane-handoff-error"])
+def test_assistant_loop_drains_primary_first_and_records_errors_per_ring(
+        handoff_ring, fail_on):
+    """The one assistant loop, on the plain pair and on a pool lane with a
+    handoff ring: primary work drains before handed-off work, shutdown
+    drains both rings, and a failure's index counts completions on the
+    ring that carried it (so the pool can map it to submission order)."""
+    rt = Relic(capacity=16, start_awake=True)
+    if handoff_ring:
+        rt._oring = SpscRing(2 * 16)        # what a multi-lane pool attaches
+    ran = []
+
+    def put(ring, tag):
+        target = rt._ring if ring == "primary" else rt._oring
+        assert target.push2(ran.append, (tag,))
+        rt.stats.submitted += 1
+
+    # Both rings loaded before the assistant exists, handoff ring first:
+    # the primary burst still runs first.
+    if handoff_ring:
+        for i in range(3):
+            put("handoff", f"h{i}")
+    for i in range(2):
+        put("primary", f"p{i}")
+    rt.start()
+    rt.wait()
+    assert ran == ["p0", "p1"] + (["h0", "h1", "h2"] if handoff_ring else [])
+
+    # A failure on one ring is indexed among that ring's completions only:
+    # 2 primary and 3 handoff tasks completed before it.
+    def boom(tag):
+        raise ValueError(tag)
+
+    target = rt._ring if fail_on == "primary" else rt._oring
+    put(fail_on, "ok")
+    assert target.push2(boom, ("first",))
+    rt.stats.submitted += 1
+    assert target.push2(boom, ("second",))
+    rt.stats.submitted += 1
+    rt._barrier()
+    assert rt.stats.task_errors == 2
+    if fail_on == "primary":
+        assert rt.stats.first_error_index == 3
+        assert rt.stats.first_error_handoff_index is None
+    else:
+        assert rt.stats.first_error_handoff_index == 4
+        assert rt.stats.first_error_index is None
+    with pytest.raises(ValueError, match="first"):
+        rt.wait()
+    assert rt.stats.first_error_index is None
+    assert rt.stats.first_error_handoff_index is None
+
+    # Shutdown drains every ring, primary first, even from a parked
+    # assistant that was never woken for the work.
+    rt.sleep_hint()
+    deadline = time.time() + 5
+    parks = rt.stats.parks
+    while rt.stats.parks == parks and time.time() < deadline:
+        time.sleep(0.001)
+    del ran[:]
+    if handoff_ring:
+        put("handoff", "h-last")
+    put("primary", "p-last")
+    rt.shutdown()
+    assert ran == ["p-last"] + (["h-last"] if handoff_ring else [])
+    assert rt._completed == rt.stats.submitted

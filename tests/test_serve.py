@@ -466,14 +466,6 @@ def test_serve_config_invalid_env_raises(monkeypatch, var, bad):
         resolve_serve_config()
 
 
-def test_spin_pause_every_still_importable_from_relic():
-    # Back-compat: the knob moved to repro.runtime.config but relic is
-    # where existing callers import it from.
-    from repro.core.relic import resolve_spin_pause_every as via_relic
-    from repro.runtime.config import resolve_spin_pause_every as via_config
-    assert via_relic is via_config
-
-
 # ---------------------------------------------------------------------------
 # scan-prefill contract (launch satellite)
 # ---------------------------------------------------------------------------

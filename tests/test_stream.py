@@ -2,7 +2,7 @@
 
 Covers: Pipeline/Stage/Farm basics (exactly-once, FIFO, in-order farm
 release, inline degradation), in-stream failure markers, supervision
-(dead-stage detection + ``RELIC_SUPERVISE=0`` opt-out), the structural
+(dead-stage detection), the structural
 "no locks, SPSC-only" pins the PR acceptance demands, TaskGraph
 ``streaming=True`` parity with the barriered wavefront baseline (plus the
 overlap a wavefront cannot express), the rebuilt PrefetchPipeline /
@@ -202,23 +202,6 @@ def test_dead_stage_raises_relic_dead_error():
         assert isinstance(ei.value.__cause__, SystemExit)
     finally:
         pipe.close()    # cleanup is tolerant: never raises for dead stages
-
-
-def test_supervise_opt_out(monkeypatch):
-    """RELIC_SUPERVISE=0 disables liveness probing in stages, same switch
-    as the substrate layer."""
-    monkeypatch.setenv("RELIC_SUPERVISE", "0")
-    st = Stage(lambda x: x, substrate="relic")
-    try:
-        assert st._probe_every == 0
-    finally:
-        st.close()
-    monkeypatch.setenv("RELIC_SUPERVISE", "1")
-    st = Stage(lambda x: x, substrate="relic")
-    try:
-        assert st._probe_every > 0
-    finally:
-        st.close()
 
 
 def test_worker_alive_duck_typing():

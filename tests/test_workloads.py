@@ -343,15 +343,3 @@ def test_skewed_chunked_passes_oracle_at_every_lane_count(name, lanes):
         chunked = w.chunked(scope, grain=1)
     w.check(chunked)
     assert all(results_agree(s, c) for s, c in zip(serial, chunked))
-
-
-def test_skewed_chunked_static_striping_matches_rebalanced():
-    """A/B integrity for the benchmark: rebalance=False (the PR 5 static
-    pool) must produce identical results on the same skewed workload."""
-    w = skewed("stencil")
-    serial = w.serial()
-    with TaskScope(make_scheduler("relic-pool", lanes=2,
-                                  rebalance=False)) as scope:
-        chunked = w.chunked(scope, grain=1)
-    w.check(chunked)
-    assert all(results_agree(s, c) for s, c in zip(serial, chunked))
