@@ -63,10 +63,9 @@ def attention_full(
     *,
     causal: bool,
     q_offset: jax.Array | int = 0,
-    kv_len: Optional[jax.Array] = None,
     prefix_len: Optional[int] = None,
 ) -> jax.Array:
-    """Unchunked reference / decode path (scores materialized)."""
+    """Unchunked reference path (scores materialized)."""
     n_kv = k.shape[2]
     qg = _grouped(q, n_kv)  # [B,Sq,Kv,G,Dh]
     scale = q.shape[-1] ** -0.5
@@ -80,9 +79,6 @@ def attention_full(
         if prefix_len is not None:  # prefix-LM: bidirectional over the prefix
             mask = mask | (kpos[None, :] < prefix_len)
         s = jnp.where(mask[None, None, None], s, NEG_INF)
-    if kv_len is not None:
-        valid = jnp.arange(sk) < kv_len
-        s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqt,btkd->bqkgd", p, v.astype(jnp.float32))
     return o.reshape(q.shape).astype(q.dtype)
@@ -187,6 +183,61 @@ def attention_chunked(
     return out.astype(q.dtype)
 
 
+def _terms(x: jax.Array, dtype) -> list[jax.Array]:
+    """``x`` as terms of ``dtype`` that sum to it: ``x`` itself where it has
+    that dtype, else its rounding and the rounding of the rest. For f32 in
+    bf16 the two hold ``x`` to ~2^-18, so a matmul of bf16 operands keeps
+    f32's products."""
+    if x.dtype == jnp.dtype(dtype):
+        return [x]
+    hi = x.astype(dtype)
+    return [hi, (x - hi.astype(x.dtype)).astype(dtype)]
+
+
+def _dot_terms(spec: str, xs: list[jax.Array], cache: jax.Array) -> jax.Array:
+    """``einsum(spec, sum(xs), cache)`` in f32: the terms go through one
+    matmul, concatenated along the label ``n``, and are summed."""
+    ins, out = spec.split("->")
+    res = jnp.einsum(spec, jnp.concatenate(xs, ins.split(",")[0].index("n")),
+                     cache,
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    return sum(jnp.split(res, len(xs), out.index("n")))
+
+
+def attention_decode(
+    q: jax.Array,          # [B,1,H,Dh]
+    k: jax.Array,          # [B,T,Kv*Dh]
+    v: jax.Array,          # [B,T,Kv*Dh]
+    kv_len: jax.Array,
+) -> jax.Array:
+    """One query row against a lane-dense K/V cache; keys at ``kv_len`` and
+    after are masked. Returns [B,1,H,Dh].
+
+    Both products contract over the merged head axis on the matrix unit,
+    so the slab is read in its own layout (split to ``[B,T,Kv,Dh]``, a TPU
+    would lay the whole slab out anew). The scores take K against the
+    block-diagonal query (head k's query in rows k*Dh..(k+1)*Dh of its
+    column); the output takes P against all of V and keeps each head's own
+    block. The query has the cache's dtype and P enters as terms in it
+    (``_terms``), so the products keep f32's precision, as in
+    ``attention_full``."""
+    b, _, h, dh = q.shape
+    t, width = k.shape[1:]
+    n_kv = width // dh
+    eye = jnp.eye(n_kv, dtype=q.dtype)
+    qbd = jnp.einsum("bkgd,kj->bjdkg", _grouped(q, n_kv)[:, 0],
+                     eye).reshape(b, width, h)
+    s = _dot_terms("bcn,btc->btn", [qbd], k) * dh ** -0.5   # [B,T,H]
+    s = jnp.where((jnp.arange(t) < kv_len)[None, :, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=1)
+    full = _dot_terms("btn,btc->bnc", _terms(p, v.dtype), v)  # [B,H,Kv*Dh]
+    own = jnp.arange(width) // dh == jnp.arange(n_kv)[:, None]  # [Kv,Kv*Dh]
+    o = (full.reshape(b, n_kv, h // n_kv, width) * own[:, None]).sum(1)
+    o = o.reshape(b, h // n_kv, n_kv, dh).transpose(0, 2, 1, 3)
+    return o.reshape(b, 1, h, dh).astype(q.dtype)
+
+
 def _pick_chunk(n: int, target: int) -> int:
     """Largest divisor of n that is <= target (chunked attention tiling)."""
     for c in range(min(target, n), 0, -1):
@@ -203,7 +254,6 @@ def attention_core(
     *,
     causal: bool,
     q_offset: jax.Array | int = 0,
-    kv_len: Optional[jax.Array] = None,
     prefix_len: Optional[int] = None,
 ) -> jax.Array:
     """Dispatch: kernels (TPU) > chunked (long S) > full."""
@@ -212,7 +262,7 @@ def attention_core(
         from repro.kernels import ops  # deferred: kernels are optional
 
         return ops.flash_attention(q, k, v, causal=causal)
-    if sq > 1 and max(sq, sk) >= cfg.attn_chunk_threshold and kv_len is None:
+    if sq > 1 and max(sq, sk) >= cfg.attn_chunk_threshold:
         return attention_chunked(
             q, k, v, causal=causal,
             chunk_q=_pick_chunk(sq, cfg.attn_chunk_q),
@@ -222,7 +272,7 @@ def attention_core(
             full_unroll=not cfg.scan_layers,  # exact dry-run cost accounting
         )
     return attention_full(q, k, v, causal=causal, q_offset=q_offset,
-                          kv_len=kv_len, prefix_len=prefix_len)
+                          prefix_len=prefix_len)
 
 
 # ---------------------------------------------------------------------------
@@ -288,34 +338,39 @@ def decode_self_attention(
     cfg: ModelConfig,
     p,
     x: jax.Array,           # [B,1,D]
-    cache: dict,            # {"k": [B,T,Kv,Dh], "v": [B,T,Kv,Dh]}
+    cache: dict,            # {"k": [B,T,Kv*Dh], "v": [B,T,Kv*Dh]}
     pos: jax.Array,         # [] int32 current position
     layer: Optional[jax.Array] = None,
 ):
     """One-token decode against a fixed-length KV cache; returns (y, cache).
 
-    With ``layer``, ``cache`` holds every layer's K/V stacked
-    ([L,B,T,Kv,Dh]): only row ``(layer, :, pos)`` is written, and the layer's
-    slab is read in place, so a step that carries the stack through its
-    layer loop never copies it."""
+    K and V are stored lane-dense, heads merged into the minor axis
+    (``init_decode_cache``): the new row is written flat and the slab is
+    read over the merged axis (``attention_decode``). With ``layer``,
+    ``cache`` holds every layer's K/V stacked ([L,B,T,Kv*Dh]): only row
+    ``(layer, :, pos)`` is written, and the layer's slab is read in place,
+    so a step that carries the stack through its layer loop never copies
+    it."""
     q, k_new, v_new = _project_qkv(cfg, p, x)
     if cfg.use_rope:
         posb = jnp.broadcast_to(pos, (x.shape[0], 1))
         q = apply_rope(q, posb, cfg.rope_theta)
         k_new = apply_rope(k_new, posb, cfg.rope_theta)
+    b, _, n_kv, dh = k_new.shape
     pos = pos.astype(jnp.int32)
-    new = {"k": k_new, "v": v_new}
+    new = {"k": k_new.reshape(b, 1, n_kv * dh),
+           "v": v_new.reshape(b, 1, n_kv * dh)}
     if layer is None:
         zero = jnp.int32(0)
         cache = jax.tree.map(
             lambda a, n: jax.lax.dynamic_update_slice(
-                a, n.astype(a.dtype), (zero, pos, zero, zero)), cache, new)
+                a, n.astype(a.dtype), (zero, pos, zero)), cache, new)
         k, v = cache["k"], cache["v"]
     else:
         cache = write_layer(cache, new, layer, pos)
         slab = layer_entry(cache, layer)
         k, v = slab["k"], slab["v"]
-    o = attention_core(cfg, q, k, v, causal=False, kv_len=pos + 1)
+    o = attention_decode(q, k, v, pos + 1)
     y = _output(cfg, p, o)
     return y, cache
 
@@ -336,7 +391,13 @@ def decode_cross_attention(
 
 
 def init_decode_cache(cfg: ModelConfig, batch: int, max_len: int, n_kv: int,
-                      head_dim: int, dtype=None):
-    dtype = dtype or dt(cfg.compute_dtype)
-    shape = (batch, max_len, n_kv, head_dim)
+                      head_dim: int):
+    """A self-attention K/V cache, ``[B,T,Kv*Dh]`` per leaf. The heads share
+    the minor axis so a device's default layout keeps it there, lane-dense,
+    with time on the second-minor axis: a position's row write touches one
+    tile row per batch row. Split (``[B,T,Kv,Dh]``), a TPU lays a head
+    width that is no multiple of 128 out with time minor, and a row write
+    rewrites the whole slab."""
+    shape = (batch, max_len, n_kv * head_dim)
+    dtype = dt(cfg.compute_dtype)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}

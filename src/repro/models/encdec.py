@@ -146,8 +146,8 @@ def init_encdec_cache(cfg: ModelConfig, batch: int, cache_len: int):
 
     def one(_):
         return {
-            "k": jnp.zeros((batch, cache_len, cfg.n_kv_heads, hd), cd),
-            "v": jnp.zeros((batch, cache_len, cfg.n_kv_heads, hd), cd),
+            **attn.init_decode_cache(cfg, batch, cache_len, cfg.n_kv_heads,
+                                     hd),
             "xk": jnp.zeros((batch, enc_len, cfg.n_kv_heads, hd), cd),
             "xv": jnp.zeros((batch, enc_len, cfg.n_kv_heads, hd), cd),
         }

@@ -192,10 +192,11 @@ _PARAM_RULES: list[tuple[str, tuple]] = [
     (r"(^|/)rwkv/w_o$",           ("model", "data")),
     # decode caches: batch over data; KV time axis over model (flash-decoding
     # style split-T — GSPMD inserts the partial-softmax collectives)
-    (r"(^|/)cache/(k|v)$",        ("data", "model", None, None)),  # [B,T,H,Dh]
+    (r"(^|/)cache/(k|v)$",        ("data", "model", None)),  # [B,T,Kv*Dh]
     (r"(^|/)cache/(xk|xv)$",      ("data", "model", None, None)),  # cross-attn
-    (r"(^|/)layers/(k|v|xk|xv)$", ("data", "model", None, None)),  # encdec cache
-    (r"(^|/)shared_attn/(k|v)$",  ("data", "model", None, None)),  # zamba2 cache
+    (r"(^|/)layers/(k|v)$",       ("data", "model", None)),  # encdec cache
+    (r"(^|/)layers/(xk|xv)$",     ("data", "model", None, None)),
+    (r"(^|/)shared_attn/(k|v)$",  ("data", "model", None)),  # zamba2 cache
     (r"(^|/)cache/ssm_state$",    ("data", "model", None, None)),  # [B,H,P,N]
     (r"(^|/)cache/wkv_state$",    ("data", "model", None, None)),  # [B,H,Dh,Dh]
     (r"(^|/)cache/conv_state$",   ("data", None, "model")),        # [B,K-1,C]

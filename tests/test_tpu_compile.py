@@ -209,6 +209,21 @@ def test_phi3_merged_group_fits_and_keeps_the_cache_in_place(one_chip,
     assert join is not None and split is not None
 
 
+@pytest.mark.parametrize("step", ["prefill", "serve_step"])
+@pytest.mark.parametrize("rows", [4, 16])
+def test_phi3_kv_cache_keeps_time_off_the_lanes(one_chip, rows, step):
+    """phi3's stacked K/V leaves (``[L,B,T,Kv*Dh]``, time axis 2) enter
+    the prefill and the decode step, at the cells' 4 rows and a merged
+    group's 16, in a layout whose minor (lane) axis is not time: a
+    position's row write then touches one tile row per batch row, not
+    every tile of the layer's slab."""
+    compiled = _phi3_serve_programs(one_chip, step, rows, 512, 64)
+    cache = compiled.input_formats[0][1]["layers"]["cache"]
+    for name in ("k", "v"):
+        assert cache[name].layout.major_to_minor[-1] != 2, (
+            name, cache[name].layout)
+
+
 def _dsv3_serve_programs(one_chip, step, batch, cache_len, prompt_len):
     """The benchmark cell ``dsv3-ep32-serve-decode``'s prefill or decode
     step (DeepSeek-V3 at published widths, 1 dense and 4 MoE layers, 8
